@@ -102,7 +102,7 @@ def _mk_engine_scaled(n_shards: int):
 def _inject_table(eng: LSMEngine, keys: np.ndarray, level: int) -> None:
     vals = keys.astype(np.int32)
     table = SSTable.build(np.sort(keys), vals, level=level,
-                          created_at=eng.now, interpret=eng.interpret)
+                          created_at=eng.now)
     eng._bind_table(table)
 
 

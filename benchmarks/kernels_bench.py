@@ -156,8 +156,11 @@ def calibration_sweep(quick: bool = False) -> dict:
 
 
 def run(quick: bool = False) -> dict:
+    from repro.core.backend import compiled_supported
     out: dict = {"claims": {}}
     rng = np.random.default_rng(0)
+    interp = not compiled_supported()     # the kernels' mode, reported
+    out["kernel_mode"] = "interpret" if interp else "compiled"
 
     # merge kernel
     from repro.kernels.merge.ops import merge_dedup
@@ -169,7 +172,7 @@ def run(quick: bool = False) -> dict:
     vb = rng.integers(0, 1 << 30, n).astype(np.int32)
     mk, mv, keep, valid = merge_dedup(jnp.asarray(ka), jnp.asarray(va),
                                       jnp.asarray(kb), jnp.asarray(vb),
-                                      block=256)
+                                      block=256, interpret=interp)
     keep = np.array(keep)
     keep[valid:] = False
     rk, rv = merge_dedup_ref(ka, va, kb, vb)
@@ -179,7 +182,7 @@ def run(quick: bool = False) -> dict:
         "n": n,
         "ms": _time(lambda: merge_dedup(jnp.asarray(ka), jnp.asarray(va),
                                         jnp.asarray(kb), jnp.asarray(vb),
-                                        block=256)),
+                                        block=256, interpret=interp)),
         "oracle_agree": bool(agree),
     }
     out["claims"]["merge_matches_oracle"] = bool(agree)
@@ -189,15 +192,18 @@ def run(quick: bool = False) -> dict:
     keys = rng.choice(1 << 24, n, replace=False).astype(np.uint32)
     n_bits, k_hashes = filter_params(n, 0.01)
     filt = bloom_build(jnp.asarray(keys), n_bits, k_hashes)
-    present = bloom_probe(filt, jnp.asarray(keys), n_bits, k_hashes)
+    present = bloom_probe(filt, jnp.asarray(keys), n_bits, k_hashes,
+                          interpret=interp)
     absent_keys = rng.choice(1 << 24, 4 * n, replace=False).astype(np.uint32)
     absent_keys = np.setdiff1d(absent_keys, keys)[:n]
     fp = float(np.mean(np.asarray(
-        bloom_probe(filt, jnp.asarray(absent_keys), n_bits, k_hashes))))
+        bloom_probe(filt, jnp.asarray(absent_keys), n_bits, k_hashes,
+                    interpret=interp))))
     out["bloom"] = {
         "n": n, "fp_rate": fp, "n_bits": n_bits, "k_hashes": k_hashes,
         "probe_ms": _time(lambda: bloom_probe(filt, jnp.asarray(keys),
-                                              n_bits, k_hashes)),
+                                              n_bits, k_hashes,
+                                              interpret=interp)),
         "no_false_negatives": bool(np.asarray(present).all()),
     }
     out["claims"]["bloom_no_false_negatives"] = bool(

@@ -53,8 +53,7 @@ def _inject_table(eng: LSMEngine, rng, n: int, level: int) -> None:
     keys = np.unique(rng.integers(0, KEY_SPACE, int(n * 1.3),
                                   dtype=np.uint32))[:n]
     vals = rng.integers(0, 1 << 30, len(keys)).astype(np.int32)
-    table = SSTable.build(keys, vals, level=level, created_at=eng.now,
-                          interpret=eng.interpret)
+    table = SSTable.build(keys, vals, level=level, created_at=eng.now)
     eng._bind_table(table)
 
 

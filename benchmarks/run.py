@@ -52,6 +52,8 @@ def main():
                          "errors fail (claims reported, not gated)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     mods = [args.only] if args.only else MODULES
     n_claims = n_pass = n_err = 0

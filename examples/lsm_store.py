@@ -1,19 +1,19 @@
-"""The LSM engine as a standalone key-value store: write a workload
-through the greedy scheduler under an I/O budget, then query it —
-Bloom probes and merges execute through the Pallas kernels
-(interpret mode on CPU).  A second phase serves the same store behind
-the wall-clock ``BackgroundDriver``: the pump thread holds the engine
-lock around each quantum, and the foreground read/write path takes the
-same lock (``with eng.lock():``) so serving traffic never races
-background I/O.  A third phase serves the SAME workload through a
+"""The LSM engine as a standalone key-value store: write a workload through
+the greedy scheduler under an I/O budget, then query it — Bloom probes
+and merges run wherever the engine's execution backend sends them
+(compiled Pallas kernels on a TPU). A second phase serves the same store
+behind the wall-clock ``BackgroundDriver``: the pump thread holds the
+engine lock around each quantum, and the foreground read/write path
+takes the same lock (``with eng.lock():``) so serving traffic never
+races background I/O. A third phase serves the SAME workload through a
 4-shard ``LSMFleet``: the batched router scatters keys across shards,
 the ``FleetBackgroundDriver`` splits one global I/O budget via the fair
-arbiter, and no external locking is needed — engines lock internally.
-A final phase makes the store durable: writes (and tombstoned deletes)
-go through a group-committed WAL, a snapshot is taken mid-workload, the
+arbiter, and no external locking is needed — engines lock internally. A
+final phase makes the store durable: writes (and tombstoned deletes) go
+through a group-committed WAL, a snapshot is taken mid-workload, the
 process is "killed" at a fault-injection crash point with a torn WAL
-tail, and a fresh engine recovers — snapshot restore + budgeted replay
-— to a state bit-identical to a reference fed the durable prefix.
+tail, and a fresh engine recovers — snapshot restore + budgeted replay —
+to a state bit-identical to a reference fed the durable prefix.
 
     PYTHONPATH=src python examples/lsm_store.py
 """

@@ -27,10 +27,18 @@ construction.  With no calibration artifact the built-in default applies
 (compiled when supported, else host — the interpreter never wins a
 performance decision).
 
+On a TPU (``jax.default_backend() == "tpu"``) every data-plane op runs
+``compiled`` unless the caller forced a mode: no calibration, no
+fallback and no environment switch can move the chip's data plane onto
+the host or the interpreter, and a kernel the chip refuses raises.  A
+calibration table records the ``device_kind`` it was measured on and is
+applied only on that kind of device.
+
 The three legacy engine booleans survive as thin deprecated overrides:
 ``ExecBackend.from_legacy`` maps them to FORCED per-op modes that
-reproduce the historical dispatch bit-for-bit, so every existing
-construction site behaves unchanged.
+reproduce the historical dispatch, with the kernel mode compiled
+wherever compiled Pallas is supported unless ``interpret=True`` is
+passed explicitly.
 
 All three modes are pinned bit-identical on merge/probe/scan results by
 ``tests/test_backend.py`` (compiled skipped where unsupported).
@@ -39,25 +47,17 @@ from __future__ import annotations
 
 import functools
 import json
-import os
 from bisect import bisect_right
 from pathlib import Path
-from typing import Callable, Optional, Sequence
+from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
-try:  # jax is present everywhere the engine runs; guard for doc tooling
-    import jax.numpy as jnp
-    from repro.kernels.bloom.ops import (bloom_probe_multi,
-                                         bloom_probe_multi_host)
-    from repro.kernels.merge.ops import (merge_dedup_kway,
-                                         merge_dedup_kway_window)
-    _KERNELS = True
-except Exception:  # pragma: no cover - kernels unavailable
-    jnp = None
-    bloom_probe_multi = bloom_probe_multi_host = None
-    merge_dedup_kway = merge_dedup_kway_window = None
-    _KERNELS = False
+from repro.kernels.bloom.ops import bloom_probe_multi, bloom_probe_multi_host
+from repro.kernels.merge.ops import merge_dedup_kway
 
 from .memtable import drop_tombstones
 
@@ -73,34 +73,39 @@ CALIBRATION_PATH = Path(__file__).resolve().parents[3] / "artifacts" / \
     "bench" / "backend_calibration.json"
 
 
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU: the data plane then
+    runs compiled, whatever a calibration table says."""
+    return jax.default_backend() == "tpu"
+
+
+def device_kind() -> str:
+    """``device_kind`` of the default device (calibration tables are
+    keyed by it)."""
+    return jax.devices()[0].device_kind
+
+
 @functools.lru_cache(maxsize=1)
 def compiled_supported() -> bool:
     """Can this process lower a Pallas kernel for real (interpret=False)?
 
-    Probed ONCE with a trivial kernel: CPU XLA builds of jax that only
-    support the interpreter raise, TPU/GPU (and future CPU lowering)
-    succeed.  ``REPRO_FORCE_COMPILED=0`` force-disables (CI determinism);
-    there is deliberately no force-ENABLE — claiming compiled support the
-    backend cannot deliver would turn every kernel launch into an error.
+    False on a CPU backend, whose XLA build only runs the interpreter.
+    Elsewhere a one-tile copy kernel is compiled and run once, and any
+    lowering error PROPAGATES: a chip that refuses a kernel must fail
+    loudly, never quietly degrade the data plane to the host.
     """
-    if os.environ.get("REPRO_FORCE_COMPILED") == "0":
+    if jax.default_backend() == "cpu":
         return False
-    if not _KERNELS:
-        return False
-    try:
-        import jax
-        from jax.experimental import pallas as pl
 
-        def _copy(x_ref, o_ref):
-            o_ref[...] = x_ref[...]
+    def _copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
 
-        x = jnp.zeros((8,), jnp.uint32)
-        out = pl.pallas_call(
-            _copy, out_shape=jax.ShapeDtypeStruct((8,), jnp.uint32),
-            interpret=False)(x)
-        return bool(np.asarray(out).shape == (8,))
-    except Exception:
-        return False
+    x = jnp.zeros((8, 128), jnp.uint32)
+    out = pl.pallas_call(
+        _copy, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.uint32),
+        interpret=False)(x)
+    jax.block_until_ready(out)
+    return True
 
 
 def merge_kway_host(runs) -> tuple[np.ndarray, np.ndarray]:
@@ -128,19 +133,22 @@ def write_calibration(table: dict, path: Path | str | None = None) -> Path:
     """Persist a crossover table (the ``kernels_bench`` sweep's output).
 
     ``table`` must carry ``{"ops": {op: {"sizes": [...], "best": [...],
-    "ms": {mode: [...]}}}}``; metadata keys ride along verbatim."""
+    "ms": {mode: [...]}}}}``; metadata keys ride along verbatim, and
+    ``device_kind`` defaults to the device this process measured on."""
     path = Path(path) if path is not None else CALIBRATION_PATH
     path.parent.mkdir(parents=True, exist_ok=True)
     payload = dict(table)
     payload.setdefault("version", 1)
+    payload.setdefault("device_kind", device_kind())
     path.write_text(json.dumps(payload, indent=2, sort_keys=True))
     return path
 
 
 def load_calibration(path: Path | str | None = None) -> Optional[dict]:
     """Load the crossover table; None when absent or unreadable (the
-    backend then falls back to its built-in default — a missing artifact
-    must never fail engine construction)."""
+    backend then uses its built-in default — a missing artifact must
+    never fail engine construction).  Whether the table applies to this
+    device is the backend's decision (see ``ExecBackend``)."""
     path = Path(path) if path is not None else CALIBRATION_PATH
     try:
         data = json.loads(path.read_text())
@@ -156,45 +164,46 @@ class ExecBackend:
 
     ``mode`` selects the dispatch discipline:
 
-    * ``"auto"``      — per op per size class from the measured crossover
-      table (``calibration``; loaded from the committed artifact when not
-      given), with a sane built-in default when no table exists.
+    * ``"auto"``      — compiled on a TPU; elsewhere per op per size
+      class from the measured crossover table (``calibration``; loaded
+      from the committed artifact when not given, and applied only when
+      its ``device_kind`` is this device's), with a built-in default when
+      no table applies.
     * ``"host"`` / ``"interpret"`` / ``"compiled"`` — force every op to
       one mode (differential tests and the calibration sweep use this).
 
     ``from_legacy`` maps the engine's three historical booleans
     (``use_kernels``, ``interpret``, ``scan_use_kernels``) to forced
-    per-op modes reproducing the old dispatch exactly — the deprecated
-    compatibility surface.
+    per-op modes — the deprecated compatibility surface.
 
-    Entry points return host numpy arrays plus, for kernel modes, the
-    device-resident result pair — the engine's streaming merge
-    accumulates those into its preallocated device output buffer so the
-    finished table needs no re-upload.
+    Kernel modes stage the host windows they are given onto the device
+    and return host numpy results; ``merge_kway``/``merge_kway_window``
+    also say which mode ran, so the engine's streaming merge knows whether
+    its output came from the device.
     """
 
     def __init__(self, mode: str = "auto",
                  calibration: dict | Path | str | None = None,
-                 merge_block: int = 256, interpret: bool = True,
+                 merge_block: int = 256,
                  forced: Optional[dict] = None):
         if mode not in ("auto",) + MODES:
             raise ValueError(f"unknown backend mode {mode!r}")
-        if mode == COMPILED and not compiled_supported():
-            raise ValueError("compiled Pallas is not supported by this "
-                             "XLA backend (compiled_supported() is False)")
         self.mode = mode
         self.merge_block = int(merge_block)
-        #: Pallas execution mode hint for per-table probes
-        #: (``SSTable.interpret``): kernels interpret unless compiled.
-        self.interpret = bool(interpret) and mode != COMPILED
         self._forced: dict[str, str] = dict(forced or {})
         if mode in MODES:
             for op in OPS:
                 self._forced.setdefault(op, mode)
+        if COMPILED in self._forced.values() and not compiled_supported():
+            raise ValueError("compiled Pallas is not supported by this "
+                             "XLA backend (compiled_supported() is False)")
         if isinstance(calibration, (str, Path)):
             calibration = load_calibration(calibration)
         elif calibration is None and mode == "auto" and not self._forced:
             calibration = load_calibration()
+        if calibration is not None and \
+                calibration.get("device_kind") != device_kind():
+            calibration = None        # measured on another kind of device
         self.calibration = calibration
         # legacy-compat reporting flags (engine properties read these)
         self.legacy_use_kernels: Optional[bool] = None
@@ -202,33 +211,35 @@ class ExecBackend:
 
     # ------------------------------------------------------------- legacy
     @classmethod
-    def from_legacy(cls, use_kernels: bool = True, interpret: bool = True,
+    def from_legacy(cls, use_kernels: bool = True,
+                    interpret: Optional[bool] = None,
                     scan_use_kernels: Optional[bool] = None,
                     merge_block: int = 256) -> "ExecBackend":
         """DEPRECATED mapping of the three historical engine booleans to
-        forced per-op modes, bit-for-bit equal to the old dispatch:
+        forced per-op modes:
 
-        * merges: kernel iff ``use_kernels`` (interpret per flag);
-        * probe: always the fused kernel, interpret per flag;
+        * the kernel mode is ``interpret`` only when ``interpret=True`` is
+          passed; otherwise compiled where supported, else interpret;
+        * merges: kernel iff ``use_kernels``;
+        * probe: always the fused kernel;
         * scans: ``scan_use_kernels`` — None (auto) means kernel only
-          when compiled (``use_kernels and not interpret``), True/False
-          force a side.
+          when compiled, True/False force a side.
         """
-        use_kernels = bool(use_kernels) and merge_dedup_kway is not None
+        if interpret is None:
+            interpret = not compiled_supported()
         kmode = INTERPRET if interpret else COMPILED
+        use_kernels = bool(use_kernels)
         if scan_use_kernels is None:
             scan_kernel = use_kernels and not interpret
         else:
-            scan_kernel = bool(scan_use_kernels) and \
-                merge_dedup_kway is not None
+            scan_kernel = bool(scan_use_kernels)
         forced = {
             "probe_multi": kmode,
             "merge_kway": kmode if use_kernels else HOST,
             "merge_kway_window": kmode if use_kernels else HOST,
             "scan_merge": kmode if scan_kernel else HOST,
         }
-        be = cls(mode="auto", merge_block=merge_block, interpret=interpret,
-                 forced=forced)
+        be = cls(mode="auto", merge_block=merge_block, forced=forced)
         be.legacy_use_kernels = use_kernels
         be.legacy_scan_use_kernels = scan_kernel
         return be
@@ -240,45 +251,37 @@ class ExecBackend:
     def decide(self, op: str, size: int) -> str:
         """The dispatch decision for one launch: which mode runs ``op``
         over ``size`` elements.  Forced modes (legacy booleans, forced
-        backend) win; otherwise the measured crossover table's best mode
-        for the nearest size class at or below ``size``; otherwise the
-        built-in default.  A ``compiled`` verdict degrades to the next
-        measured-best (or the default) when this process cannot lower
-        compiled Pallas."""
+        backend) win; on a TPU everything else is compiled; otherwise the
+        measured crossover table's best mode for the nearest size class
+        at or below ``size``, or the built-in default.  Off the TPU a
+        ``compiled`` table verdict this process cannot lower degrades to
+        the next measured-best mode."""
         mode = self._forced.get(op)
-        if mode is None:
-            mode = self._lookup(op, size)
-        if mode == COMPILED and not compiled_supported():
-            mode = self._lookup(op, size, exclude=COMPILED) \
-                if self._forced.get(op) is None else INTERPRET
-        return mode
+        if mode is not None:
+            return mode
+        if on_tpu():
+            return COMPILED
+        return self._lookup(op, size)
 
-    def _lookup(self, op: str, size: int,
-                exclude: Optional[str] = None) -> str:
+    def _lookup(self, op: str, size: int) -> str:
         cal = self.calibration
         tab = None
         if cal is not None:
             ops = cal.get("ops", {})
             tab = ops.get(op) or ops.get(_OP_ALIAS.get(op, op))
-        if not tab:
-            return HOST if exclude == COMPILED else self._default_mode()
-        sizes = tab.get("sizes") or []
-        best = tab.get("best") or []
+        sizes = (tab or {}).get("sizes") or []
+        best = (tab or {}).get("best") or []
         if not sizes or len(best) != len(sizes):
-            return HOST if exclude == COMPILED else self._default_mode()
+            return self._default_mode()
         i = max(0, min(bisect_right(sizes, int(size)) - 1, len(sizes) - 1))
         mode = best[i]
-        if mode == exclude or (mode == COMPILED
-                               and not compiled_supported()):
+        if mode == COMPILED and not compiled_supported():
             ms = tab.get("ms", {})
             live = [(ms[m][i], m) for m in (HOST, INTERPRET)
                     if m in ms and ms[m] is not None
                     and ms[m][i] is not None]
             mode = min(live)[1] if live else HOST
         return mode if mode in MODES else HOST
-
-    def _interp(self, mode: str) -> bool:
-        return mode != COMPILED
 
     # -------------------------------------------------------- entry points
     def probe_multi(self, filts, meta, keys,
@@ -293,56 +296,53 @@ class ExecBackend:
         if mode == HOST and filts_host is not None:
             return bloom_probe_multi_host(filts_host, np.asarray(meta),
                                           np.asarray(keys, np.uint32))
-        return np.asarray(bloom_probe_multi(
-            filts, meta, keys, interpret=self._interp(mode)))
+        return bloom_probe_multi(filts, meta, keys,
+                                 interpret=mode == INTERPRET)
 
-    def merge_kway(self, runs, drop_value: Optional[int] = None,
-                   runs_dev=None):
+    def _kernel_merge(self, runs, mode: str, drop_value: Optional[int]):
+        return (*merge_dedup_kway(runs, block=self.merge_block,
+                                  interpret=mode == INTERPRET,
+                                  drop_value=drop_value), mode)
+
+    @staticmethod
+    def _host_merge(runs, drop_value: Optional[int]):
+        mk, mv = merge_kway_host(runs)
+        if drop_value is not None:
+            mk, mv = drop_tombstones(mk, mv)
+        return mk, mv
+
+    def merge_kway(self, runs, drop_value: Optional[int] = None):
         """One-shot k-way newest-wins merge (newest run first).  Returns
-        ``(keys_np, vals_np, dev)`` — ``dev`` is the device-resident
-        ``(keys, vals)`` pair when a kernel produced it, else None."""
+        ``(keys_np, vals_np, mode)``, ``mode`` being the one that ran."""
         size = sum(len(k) for k, _ in runs)
         mode = self.decide("merge_kway", size)
         if mode == HOST:
-            mk, mv = merge_kway_host(runs)
-            if drop_value is not None:
-                mk, mv = drop_tombstones(mk, mv)
-            return mk, mv, None
-        dev_runs = runs_dev() if callable(runs_dev) else (runs_dev or runs)
-        dk, dv = merge_dedup_kway(dev_runs, block=self.merge_block,
-                                  interpret=self._interp(mode),
-                                  drop_value=drop_value)
-        return np.asarray(dk), np.asarray(dv), (dk, dv)
+            return (*self._host_merge(runs, drop_value), HOST)
+        return self._kernel_merge(runs, mode, drop_value)
 
     def merge_kway_window(self, runs, starts, stops,
-                          drop_value: Optional[int] = None, runs_dev=None):
+                          drop_value: Optional[int] = None):
         """Streaming-quantum window merge: merge only the
-        ``[starts[i], stops[i])`` slice of each run (the engine cuts at a
-        global key boundary, so windows compose bit-identically).
-        ``runs`` are host mirrors; ``runs_dev`` (list or thunk) supplies
-        the device-resident arrays for kernel modes.  Returns
-        ``(keys_np, vals_np, dev)`` like ``merge_kway``."""
+        ``[starts[i], stops[i])`` slice of each host run (the engine cuts
+        at a global key boundary, so windows compose bit-identically).
+        Returns ``(keys_np, vals_np, mode)`` like ``merge_kway``; an
+        empty window keeps its position's age rank."""
         size = int(sum(e - s for s, e in zip(starts, stops)))
         mode = self.decide("merge_kway_window", size)
-        if mode == HOST:
-            windows = [(k[s:e], v[s:e])
-                       for (k, v), s, e in zip(runs, starts, stops)
-                       if e > s]
-            if not windows:
-                return (np.empty(0, np.uint32), np.empty(0, np.int32),
-                        None)
-            if len(windows) == 1:
-                wk, wv = windows[0]
-            else:
-                wk, wv = merge_kway_host(windows)
+        windows = [(k[s:e], v[s:e])
+                   for (k, v), s, e in zip(runs, starts, stops)]
+        if mode != HOST:
+            return self._kernel_merge(windows, mode, drop_value)
+        windows = [w for w in windows if len(w[0])]
+        if not windows:
+            return np.empty(0, np.uint32), np.empty(0, np.int32), HOST
+        if len(windows) == 1:
+            wk, wv = windows[0]
             if drop_value is not None:
                 wk, wv = drop_tombstones(wk, wv)
-            return np.ascontiguousarray(wk), np.ascontiguousarray(wv), None
-        dev_runs = runs_dev() if callable(runs_dev) else (runs_dev or runs)
-        dk, dv = merge_dedup_kway_window(
-            dev_runs, list(starts), list(stops), block=self.merge_block,
-            interpret=self._interp(mode), drop_value=drop_value)
-        return np.asarray(dk), np.asarray(dv), (dk, dv)
+        else:
+            wk, wv = self._host_merge(windows, drop_value)
+        return np.ascontiguousarray(wk), np.ascontiguousarray(wv), HOST
 
     def scan_merge(self, runs,
                    drop_value: Optional[int] = None) -> tuple[np.ndarray,
@@ -352,14 +352,8 @@ class ExecBackend:
         size = sum(len(k) for k, _ in runs)
         mode = self.decide("scan_merge", size)
         if mode == HOST:
-            mk, mv = merge_kway_host(runs)
-            if drop_value is not None:
-                mk, mv = drop_tombstones(mk, mv)
-            return mk, mv
-        dk, dv = merge_dedup_kway(runs, block=self.merge_block,
-                                  interpret=self._interp(mode),
-                                  drop_value=drop_value)
-        return np.asarray(dk), np.asarray(dv)
+            return self._host_merge(runs, drop_value)
+        return self._kernel_merge(runs, mode, drop_value)[:2]
 
     # ------------------------------------------------------------- info
     def describe(self) -> dict:
@@ -370,6 +364,7 @@ class ExecBackend:
             "forced": dict(self._forced),
             "calibrated": self.calibration is not None,
             "compiled_supported": compiled_supported(),
+            "on_tpu": on_tpu(),
             "merge_block": self.merge_block,
         }
 

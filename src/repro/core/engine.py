@@ -79,7 +79,7 @@ STREAMED so one quantum costs O(quantum): a merge keeps per-run
 cursors, cuts each window at a global key boundary (the merge-path
 pivot — no equal-key group straddles windows, so concatenated windows
 are bit-identical to the one-shot merge), and accumulates output into
-preallocated host + device buffers that ``_finish_merge`` binds as O(1)
+preallocated host buffers that ``_finish_merge`` binds as O(1)
 views.  Flushes larger than the remaining quantum carry their overshoot
 as per-tree debt repaid before new work.
 
@@ -174,17 +174,19 @@ answer.  ``health()`` exposes the fault-plane counters.
 from __future__ import annotations
 
 import bisect
-import functools
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from .backend import ExecBackend, merge_kway_host  # noqa: F401 (re-export:
-                                                   # the fleet's scan gather
-                                                   # shares the host merge)
+from repro.kernels.bloom.ops import set_stack_row
+from .backend import HOST, ExecBackend, merge_kway_host  # noqa: F401
+                                        # (merge_kway_host re-export: the
+                                        # fleet's scan gather shares it)
 from .component import Component, MergeOp
 from .component import LSMTree as ComponentTree
 from .constraints import ComponentConstraint, NoConstraint
@@ -196,34 +198,7 @@ from .scheduler import (FairScheduler, MergeScheduler,
                         apportion_largest_remainder)
 from .sstable import SSTable
 
-try:  # the kernels need jax; engine tests always have it
-    from repro.kernels.bloom.ops import set_stack_row
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    set_stack_row = None
-    jax = jnp = None
-
-
 ENTRY_BYTES = 1024  # paper's 1 KB records: 1 entry == 1 KB of I/O budget
-
-
-def _next_pow2(n: int) -> int:
-    """Smallest power of two >= n (>= 1)."""
-    return 1 << max(n - 1, 0).bit_length()
-
-
-if jax is not None:
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def _write_window(buf, win, start):
-        """Fold one merge window into the device accumulation buffer.
-        The buffer is DONATED so backends with input-output aliasing
-        update it in place (O(window), no O(buffer) copy); windows are
-        pow2-padded by the caller so the jit cache holds O(log cap)
-        shapes per merge instead of one entry per distinct window."""
-        return jax.lax.dynamic_update_slice(buf, win, (start,))
-else:  # pragma: no cover - kernels unavailable
-    _write_window = None
 
 
 @dataclass
@@ -382,13 +357,9 @@ class _RunningMerge:
     # finished table as O(1) views — no O(merge-size) concatenate
     buf_keys: Optional[np.ndarray] = None
     buf_vals: Optional[np.ndarray] = None
-    # device accumulation (kernel windows only): the window outputs are
-    # folded into a donated device buffer so the finished table adopts
-    # device-resident arrays without a re-upload.  ``dev_ok`` drops to
-    # False permanently once any window ran on the host path.
-    dev_keys: Optional["jnp.ndarray"] = field(default=None, repr=False)
-    dev_vals: Optional["jnp.ndarray"] = field(default=None, repr=False)
-    dev_ok: bool = True
+    # True while every window ran on the device: the finished table then
+    # keeps a device copy of its output (placed once, at finish)
+    on_device: bool = True
     emitted: int = 0           # post-dedup entries emitted so far
     tombs_in: int = 0          # input tombstones seen in consumed windows
                                # (counted per quantum: O(consumed), so the
@@ -614,22 +585,17 @@ class LSMTree:
             view = self._read_view()
             if view.tables:
                 filts, meta = self._view_filters(view)
-                if filts is not None:
-                    # probe the full stack (capacity rows, <= 2x live
-                    # tables); each table's row is its own stack_slot —
-                    # no gather.  The backend picks host vs kernel; the
-                    # host path probes the stack's host mirror.
-                    probed = self.group.backend.probe_multi(
-                        filts, meta, keys,
-                        filts_host=self._fstack.filts_np)
-                else:  # pragma: no cover - kernels unavailable
-                    probed = None
+                # probe the full stack (capacity rows, <= 2x live
+                # tables); each table's row is its own stack_slot — no
+                # gather.  The backend picks host vs kernel; the host
+                # path probes the stack's host mirror.
+                probed = self.group.backend.probe_multi(
+                    filts, meta, keys, filts_host=self._fstack.filts_np)
                 for table in view.tables:
                     pend = ~resolved
                     if not pend.any():
                         break
-                    maybe_t = probed[table.stack_slot] \
-                        if probed is not None else np.ones(q, bool)
+                    maybe_t = probed[table.stack_slot]
                     cand = pend & maybe_t
                     self.stats["bloom_skips"] += int((pend & ~maybe_t).sum())
                     if not cand.any():
@@ -704,8 +670,7 @@ class LSMTree:
             keys, vals = mt.seal()
             table = SSTable.build(keys, vals,
                                   level=self.policy.flush_target_level(),
-                                  created_at=g.now,
-                                  interpret=g.interpret)
+                                  created_at=g.now)
             self._bind_table(table)
             self.stats["flushes"] += 1
             self.stats["flush_bytes"] += len(keys) * ENTRY_BYTES
@@ -900,16 +865,15 @@ class LSMTree:
                 int((rm.run_vals[i][starts[i]:stops[i]]
                      == TOMBSTONE).sum())
                 for i in range(len(rm.tables)))
-        wk, wv, dev = self.group.backend.merge_kway_window(
+        wk, wv, mode = self.group.backend.merge_kway_window(
             list(zip(rm.run_keys, rm.run_vals)),
-            starts.tolist(), stops.tolist(), drop_value=drop,
-            runs_dev=lambda: [(t.keys, t.vals) for t in rm.tables])
+            starts.tolist(), stops.tolist(), drop_value=drop)
         take = len(wk)
         assert take <= max(quantum, 1), "window emitted beyond its quantum"
         rm.cursors = stops
         rm.buf_keys[rm.emitted:rm.emitted + take] = wk
         rm.buf_vals[rm.emitted:rm.emitted + take] = wv
-        self._accumulate_device(rm, dev, take)
+        rm.on_device &= mode != HOST
         rm.emitted += take
         rm.op.written += take
         self.stats["merge_bytes"] += take * ENTRY_BYTES
@@ -917,34 +881,6 @@ class LSMTree:
         if int((rm.lens - rm.cursors).sum()) == 0:
             self.group._finish_merge(rm)
         return take
-
-    def _accumulate_device(self, rm: _RunningMerge, dev, take: int) -> None:
-        """Fold a kernel window's device-resident output into the merge's
-        device accumulation buffer (allocated lazily at 2x output
-        capacity so a pow2-padded window never clamps over earlier data;
-        the pad tail is overwritten by the next window or sliced off at
-        finish).  One host-mode window drops the buffer for good — the
-        finished table then falls back to lazy upload on first kernel
-        use, which is exactly what a host-merged table wants anyway."""
-        if not rm.dev_ok:
-            return
-        if dev is None or _write_window is None:
-            rm.dev_keys = rm.dev_vals = None
-            rm.dev_ok = False
-            return
-        if take == 0:
-            return
-        if rm.dev_keys is None:
-            cap = 2 * max(int(rm.lens.sum()), 1)
-            rm.dev_keys = jnp.zeros(cap, jnp.uint32)
-            rm.dev_vals = jnp.zeros(cap, jnp.int32)
-        dk, dv = dev
-        pad = _next_pow2(take) - take
-        if pad:
-            dk = jnp.pad(dk, (0, pad))
-            dv = jnp.pad(dv, (0, pad))
-        rm.dev_keys = _write_window(rm.dev_keys, dk, rm.emitted)
-        rm.dev_vals = _write_window(rm.dev_vals, dv, rm.emitted)
 
     def _materialize_merge(self, rm: _RunningMerge):
         """LEGACY one-shot path (``streaming_merge=False``; kept as the
@@ -960,8 +896,7 @@ class LSMTree:
             rm.tombs_in = sum(int((t._host()[1] == TOMBSTONE).sum())
                               for t in rm.inputs)
         mk, mv, _ = self.group.backend.merge_kway(
-            [t._host() for t in tables], drop_value=drop,
-            runs_dev=lambda: [(t.keys, t.vals) for t in tables])
+            [t._host() for t in tables], drop_value=drop)
         rm.merged_keys, rm.merged_vals = mk, mv
 
     def _advance_merge_oneshot(self, rm: _RunningMerge, quantum: int) -> int:
@@ -992,13 +927,9 @@ class LSMTree:
         else:  # finished before any quantum ran (all-empty inputs)
             keys = np.empty(0, np.uint32)
             vals = np.empty(0, np.int32)
-        dev_pair = None
-        if rm.dev_ok and rm.dev_keys is not None:
-            # ONE device slice binds the accumulated kernel output — the
-            # finished table adopts it, so the merge→flush→probe plane
-            # never re-uploads what a kernel already produced on device
-            dev_pair = (rm.dev_keys[:rm.emitted],
-                        rm.dev_vals[:rm.emitted])
+        # a merge that ran every window on the device leaves its output
+        # there: each finished table adopts a device copy, placed once
+        on_device = rm.on_device and rm.buf_keys is not None
         stamp = max(t.data_stamp for t in rm.inputs)
         if rm.drop:
             # every input tombstone died here: winners to the drop mask,
@@ -1016,10 +947,11 @@ class LSMTree:
                        if t.component.cid not in in_cids]
         outs = self.policy.complete_merge(self.meta, rm.op, self.group.now)
         # partitioned policies may split the output into several files
-        def _bind(comp, ks, vs, dev=None):
+        def _bind(comp, ks, vs):
+            dev = (jax.device_put(ks), jax.device_put(vs)) \
+                if on_device and len(ks) else None
             table = SSTable.build(ks, vs, level=comp.level,
-                                  created_at=self.group.now,
-                                  interpret=self.group.interpret, dev=dev)
+                                  created_at=self.group.now, dev=dev)
             table.component = comp
             table.data_stamp = stamp
             comp.stamp = float(stamp)
@@ -1038,20 +970,17 @@ class LSMTree:
             self.tables[comp.cid] = table
 
         if len(outs) == 1:
-            _bind(outs[0], keys, vals, dev_pair)
+            _bind(outs[0], keys, vals)
         else:
             # contiguous slice VIEWS at np.array_split's boundaries (the
-            # historical split), not index-gather copies; the device
-            # accumulation (when live) splits at the same boundaries
+            # historical split), not index-gather copies
             n = max(len(outs), 1)
             sizes = np.full(n, len(keys) // n, np.int64)
             sizes[:len(keys) % n] += 1
             bounds = np.concatenate([[0], np.cumsum(sizes)])
             for j, comp in enumerate(outs):
                 lo, hi = int(bounds[j]), int(bounds[j + 1])
-                dv = (dev_pair[0][lo:hi], dev_pair[1][lo:hi]) \
-                    if dev_pair is not None else None
-                _bind(comp, keys[lo:hi], vals[lo:hi], dv)
+                _bind(comp, keys[lo:hi], vals[lo:hi])
         # bisect-insert the outputs at their (-stamp, level) rank: all
         # outputs of one merge share the rank (same stamp, same level)
         # and hold disjoint key ranges, so inserting them adjacently
@@ -1089,8 +1018,7 @@ class LSMTree:
         ``flushed_lsn`` (this tree's WAL replay origin)."""
         for keys, vals, tmeta in tables:
             t = SSTable.build(keys, vals, level=int(tmeta["level"]),
-                              created_at=float(tmeta["created_at"]),
-                              interpret=self.group.interpret)
+                              created_at=float(tmeta["created_at"]))
             t.data_stamp = int(tmeta["stamp"])
             t.component.stamp = float(tmeta["stamp"])
             t.seal_checksum()
@@ -1157,8 +1085,9 @@ class StorageGroup:
     def __init__(self, policy: MergePolicy, scheduler: MergeScheduler,
                  constraint: ComponentConstraint | None = None,
                  memtable_entries: int = 4096, num_memtables: int = 2,
-                 unique_keys: float = 1e6, use_kernels: bool = True,
-                 merge_block: int = 256, interpret: bool = True,
+                 unique_keys: float = 1e6,
+                 use_kernels: Optional[bool] = None,
+                 merge_block: int = 256, interpret: Optional[bool] = None,
                  scan_use_kernels: Optional[bool] = None,
                  streaming_merge: bool = True,
                  wal=None, group_commit_entries: int = 512,
@@ -1182,16 +1111,20 @@ class StorageGroup:
                                          # (``enable_scrub``)
         self._health = {"enospc_stalls": 0}
         # -- execution backend (group-owned): every kernel-vs-host
-        # decision lives here.  The three legacy booleans map to a
-        # forced-dispatch backend reproducing the old behavior exactly.
+        # decision lives here.  With no legacy boolean given the group
+        # dispatches in "auto" mode (compiled on a TPU); the booleans map
+        # to a forced-dispatch backend, interpreting kernels only when
+        # ``interpret=True`` is passed explicitly.
+        legacy = (use_kernels, interpret, scan_use_kernels)
+        if backend is None and legacy == (None, None, None):
+            backend = "auto"
         if backend is None:
             backend = ExecBackend.from_legacy(
-                use_kernels=use_kernels, interpret=interpret,
+                use_kernels=use_kernels is not False, interpret=interpret,
                 scan_use_kernels=scan_use_kernels,
                 merge_block=merge_block)
         elif isinstance(backend, str):
-            backend = ExecBackend(mode=backend, merge_block=merge_block,
-                                  interpret=interpret)
+            backend = ExecBackend(mode=backend, merge_block=merge_block)
         self.backend = backend
         self.merge_block = int(backend.merge_block)
         self.streaming_merge = bool(streaming_merge)
@@ -1263,10 +1196,6 @@ class StorageGroup:
         if lk is not None:
             return lk
         return self.backend.decide("merge_kway", 1 << 20) != "host"
-
-    @property
-    def interpret(self) -> bool:
-        return self.backend.interpret
 
     @property
     def scan_use_kernels(self) -> bool:

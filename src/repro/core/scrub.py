@@ -177,8 +177,7 @@ class Scrubber:
                 created_at: float) -> None:
         """Bind repaired content at the quarantined table's exact
         (stamp, level) rank, so newest-wins ordering is unchanged."""
-        t = SSTable.build(keys, vals, level=level, created_at=created_at,
-                          interpret=self.group.interpret)
+        t = SSTable.build(keys, vals, level=level, created_at=created_at)
         t.data_stamp = int(stamp)
         t.component.stamp = float(stamp)
         t.seal_checksum()
@@ -263,7 +262,7 @@ class Scrubber:
             sv = np.array([kv[int(k)] for k in sk], np.int32)
             run = SSTable.build(sk, sv,
                                 level=tree.policy.flush_target_level(),
-                                created_at=g.now, interpret=g.interpret)
+                                created_at=g.now)
             tree._bind_table(run)
         self._queue = [(t, c) for t, c in self._queue
                        if t != tree.tree_id]    # stale cids of this pass

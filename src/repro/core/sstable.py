@@ -1,24 +1,23 @@
 """Immutable sorted-run component for the real engine.
 
-The data plane is JAX: Bloom filters are built/probed by the Pallas bloom
-kernel pair, point lookups are vectorized sorted searches, and merges
-(in engine.py) run through the execution backend (``core/backend.py``).
-One SSTable corresponds to one scheduling-plane ``Component`` so the
-paper's policies/schedulers drive real bytes.
+Bloom filters are built on the host and probed by the fused Pallas
+kernel, point lookups are vectorized sorted searches, and merges (in
+engine.py) run through the execution backend (``core/backend.py``).  One
+SSTable corresponds to one scheduling-plane ``Component`` so the paper's
+policies/schedulers drive real bytes.
 
 Residency contract: the HOST mirrors (``keys_np``/``vals_np``) are the
 authoritative storage — the read plane ``np.searchsorted``s them without
-a device sync per lookup, and ``build`` never copies them.  The DEVICE
-arrays (``keys``/``vals`` properties) materialize LAZILY on first kernel
-use, or are adopted directly when the caller already holds
-device-resident output (the engine's streaming merge passes its
-accumulated device buffer via ``dev=``), so a table built from a
-device-side merge is never re-uploaded and a table only ever touched by
-host-path ops never pays for a device copy at all.
+a device sync per lookup, merges stage their windows from them, and
+``build`` never copies them.  The DEVICE arrays (``keys``/``vals``
+properties) materialize LAZILY on first use, or are adopted at build
+when the engine's streaming merge ran on the device (``dev=``: the
+output lands there once, at the merge's finish), so a table only ever
+touched by host-path ops never pays for a device copy at all.
 
-``interpret`` selects the Pallas execution mode for this table's probe
-kernel (interpret=True for CPU tests, False for compiled runs); the
-engine plumbs it down from its backend.
+Point lookups never probe one table at a time: the engine's batch plane
+screens every table in one fused multi-table probe (``ExecBackend``) and
+calls ``search`` only for survivors.
 """
 from __future__ import annotations
 
@@ -28,7 +27,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.bloom.ops import bloom_build, bloom_probe, filter_params
+from repro.kernels.bloom.ops import bloom_build, filter_params
 from .component import Component
 from .iostack import data_crc32
 from .memtable import scan_window, sorted_lookup
@@ -38,10 +37,6 @@ from .memtable import scan_window, sorted_lookup
 class SSTable:
     keys_np: np.ndarray                # (n,) uint32, sorted asc, unique —
     vals_np: np.ndarray                # authoritative host mirrors
-    bloom: jnp.ndarray = None          # uint32 words, built LAZILY on the
-                                       # first probe/stack sync — never on
-                                       # the background (flush/merge) path,
-                                       # whose quanta must stay O(quantum)
     n_bits: int = 0
     k_hashes: int = 0
     component: Optional[Component] = None
@@ -49,18 +44,20 @@ class SSTable:
                                        # flush; max over inputs at merge
     stack_slot: int = -1               # row in the engine's persistent
                                        # filter stack (set by its sync)
-    interpret: bool = True             # Pallas mode for probe kernels
     crc32: Optional[int] = None        # content checksum sealed at bind
                                        # (``data_crc32``); the scrub pass
                                        # re-verifies it to catch bit-rot
-    bloom_np: Optional[np.ndarray] = None
+    bloom_np: Optional[np.ndarray] = None  # uint32 filter words, built
+                                           # LAZILY by ``bloom_host`` — never
+                                           # on the background (flush/merge)
+                                           # path, whose quanta must stay
+                                           # O(quantum)
     _keys_dev: Optional[jnp.ndarray] = field(default=None, repr=False)
     _vals_dev: Optional[jnp.ndarray] = field(default=None, repr=False)
 
     @classmethod
     def build(cls, keys, vals, level: int = 0, created_at: float = 0.0,
-              fpr: float = 0.01, interpret: bool = True,
-              dev: Optional[tuple] = None) -> "SSTable":
+              fpr: float = 0.01, dev: Optional[tuple] = None) -> "SSTable":
         # Host-first: the flush/merge call sites already hold numpy
         # arrays (``MemTable.seal`` output / the streaming merge's
         # preallocated output buffer), so component bounds come from the
@@ -76,7 +73,7 @@ class SSTable:
         # the Bloom filter itself is NOT built here: flush/merge
         # completions run under the engine lock in scheduler quanta, and
         # an O(n) filter build there is exactly the compute cliff the
-        # bounded background plane forbids.  ``_ensure_bloom`` builds it
+        # bounded background plane forbids.  ``bloom_host`` builds it
         # on the first probe (point-read paths only — scans never pay).
         lo = float(keys_np[0]) / 2**32 if n else 0.0
         hi = (float(keys_np[-1]) + 1) / 2**32 if n else 1.0
@@ -84,8 +81,8 @@ class SSTable:
                          created_at=created_at)
         dk, dv = dev if dev is not None else (None, None)
         return cls(keys_np=keys_np, vals_np=vals_np, n_bits=n_bits,
-                   k_hashes=k_hashes, component=comp, interpret=interpret,
-                   _keys_dev=dk, _vals_dev=dv)
+                   k_hashes=k_hashes, component=comp, _keys_dev=dk,
+                   _vals_dev=dv)
 
     def __len__(self) -> int:
         return int(self.keys_np.shape[0])
@@ -130,30 +127,17 @@ class SSTable:
             return True
         return int(data_crc32(self.keys_np, self.vals_np)) == self.crc32
 
-    def _ensure_bloom(self) -> jnp.ndarray:
-        """Build the filter on first use (never on the background path)."""
-        if self.bloom is None:
-            self.bloom = bloom_build(jnp.asarray(self.keys, jnp.uint32),
-                                     self.n_bits, self.k_hashes)
-        return self.bloom
-
     def bloom_host(self) -> np.ndarray:
         """Host-side filter words, built/materialized once on first use
         (the engine's incremental filter stack syncs new tables' words
         from here — one O(filter) cost on the first point read after a
         flush/merge, zero on the background quanta themselves)."""
         if self.bloom_np is None:
-            self.bloom_np = np.asarray(self._ensure_bloom())
+            self.bloom_np = bloom_build(self.keys_np, self.n_bits,
+                                        self.k_hashes)
         return self.bloom_np
 
     # -- queries --------------------------------------------------------------
-    def maybe_contains(self, keys) -> np.ndarray:
-        """Bloom-filter screen (vectorized, Pallas probe kernel)."""
-        keys = jnp.asarray(keys, jnp.uint32)
-        return np.asarray(bloom_probe(self._ensure_bloom(), keys,
-                                      self.n_bits, self.k_hashes,
-                                      interpret=self.interpret))
-
     def search(self, keys) -> tuple[np.ndarray, np.ndarray]:
         """Sorted-search lookup WITHOUT the bloom screen: (found mask,
         values).  The engine's batch plane calls this only for keys the
@@ -164,24 +148,6 @@ class SSTable:
             return np.zeros(len(keys), bool), np.zeros(len(keys), np.int32)
         sk, sv = self._host()
         return sorted_lookup(sk, sv, keys)
-
-    def get_batch(self, keys) -> tuple[np.ndarray, np.ndarray]:
-        """(found mask, values) for a key batch; bloom screen + sorted
-        search only for survivors."""
-        keys = np.asarray(keys, np.uint32)
-        maybe = self.maybe_contains(keys)
-        found = np.zeros(len(keys), bool)
-        vals = np.zeros(len(keys), np.int32)
-        if maybe.any():
-            f, v = self.search(keys[maybe])
-            idx = np.flatnonzero(maybe)
-            found[idx] = f
-            vals[idx[f]] = v[f]
-        return found, vals
-
-    def get(self, key: int):
-        found, vals = self.get_batch(np.array([key], np.uint32))
-        return int(vals[0]) if found[0] else None
 
     def scan_range(self, lo: int, hi: int) -> tuple[np.ndarray, np.ndarray]:
         """All (key, value) with lo <= key < hi — a zero-copy

@@ -1,4 +1,4 @@
-"""Public Bloom-filter API: build (XLA scatter, once per component) +
+"""Public Bloom-filter API: build (host numpy, once per component) +
 probe (Pallas kernel, the per-lookup hot path)."""
 from __future__ import annotations
 
@@ -9,8 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bloom import (bit_positions, bloom_probe_kernel,
-                    bloom_probe_multi_kernel)
+from .bloom import bloom_probe_multi_kernel, reciprocal
+from .ref import _hash_np
 
 
 def filter_params(n_keys: int, fpr: float = 0.01) -> tuple[int, int]:
@@ -23,31 +23,30 @@ def filter_params(n_keys: int, fpr: float = 0.01) -> tuple[int, int]:
     return n_bits, min(k, 16)
 
 
-@functools.partial(jax.jit, static_argnames=("n_bits", "k_hashes"))
-def bloom_build(keys, n_bits: int, k_hashes: int):
-    """Build the filter as uint32 words.
-
-    OR-semantics via an idempotent scatter-max into a byte-per-bit array,
-    then a vectorized pack — duplicate positions are harmless by
-    construction.
-    """
-    pos = bit_positions(keys.astype(jnp.uint32), n_bits, k_hashes).reshape(-1)
-    bits = jnp.zeros((n_bits,), jnp.uint8).at[pos].max(jnp.uint8(1))
-    lanes = jnp.left_shift(jnp.uint32(1), jnp.arange(32, dtype=jnp.uint32))
-    return jnp.sum(bits.reshape(-1, 32).astype(jnp.uint32) * lanes[None, :],
-                   axis=1, dtype=jnp.uint32)
+def bloom_build(keys, n_bits: int, k_hashes: int) -> np.ndarray:
+    """Build the filter as uint32 words (bit ``p`` is bit ``p % 32`` of
+    word ``p // 32``).  Built on the host: a table's filter is made once,
+    on the first point read after the table appears, and its words go to
+    the host mirror of the filter stack and, by one row write, to the
+    device stack — so a device build would only add a scatter compiled
+    per filter geometry and a copy back."""
+    keys = np.asarray(keys, np.uint32)
+    h1 = _hash_np(keys, 0x9E3779B9)
+    h2 = _hash_np(keys, 0x85EBCA6B) | np.uint32(1)     # odd stride
+    i = np.arange(k_hashes, dtype=np.uint32)[:, None]
+    pos = (h1[None, :] + i * h2[None, :]) % np.uint32(n_bits)
+    bits = np.zeros(n_bits, np.uint8)
+    bits[pos.reshape(-1)] = 1
+    return np.packbits(bits, bitorder="little").view("<u4").astype(np.uint32)
 
 
 def bloom_probe(filt, keys, n_bits: int, k_hashes: int, block: int = 1024,
-                interpret: bool = True):
-    """Probe keys; returns a bool maybe-present mask (no false negatives)."""
-    n = keys.shape[0]
-    pad = (-n) % block
-    kp = jnp.concatenate([keys.astype(jnp.uint32),
-                          jnp.zeros((pad,), jnp.uint32)])
-    out = bloom_probe_kernel(filt, kp, n_bits, k_hashes, block=block,
-                             interpret=interpret)
-    return out[:n].astype(bool)
+                interpret: bool = False):
+    """Probe keys against one filter; returns a bool maybe-present mask
+    (no false negatives).  A one-row stack through the fused kernel."""
+    meta = np.array([[n_bits, k_hashes]], np.uint32)
+    return bloom_probe_multi(jnp.asarray(filt)[None], meta, keys,
+                             block=block, interpret=interpret)[0]
 
 
 def stack_filters(filters, n_bits_list, k_hashes_list):
@@ -100,7 +99,6 @@ def bloom_probe_multi_host(filts_np: np.ndarray, meta: np.ndarray,
     rows of (n_bits, k_hashes).  Returns a (tables, keys) bool matrix.
     Rows iterate in Python (tables are tens, keys are the batch — the
     inner work is vectorized numpy over (k, q))."""
-    from .ref import _hash_np
     keys = np.asarray(keys, np.uint32)
     t, q = int(filts_np.shape[0]), len(keys)
     out = np.zeros((t, q), bool)
@@ -121,19 +119,22 @@ def bloom_probe_multi_host(filts_np: np.ndarray, meta: np.ndarray,
 
 
 def bloom_probe_multi(filts, meta, keys, block: int = 1024,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """Probe one key batch against a stack of padded filters (see
     ``stack_filters``) in a single fused launch; returns a (tables, keys)
-    bool maybe-present matrix (no false negatives per table)."""
+    bool maybe-present matrix (no false negatives per table).  The batch
+    is padded on the host to a power-of-two number of blocks, so a
+    serving loop compiles O(log batch) programs, not one per size."""
     t = filts.shape[0]
-    n = keys.shape[0]
+    n = len(keys)
     if t == 0 or n == 0:
         return np.zeros((t, n), bool)
     meta = np.asarray(meta, np.uint32)
-    pad = (-n) % block
-    kp = jnp.concatenate([jnp.asarray(keys, jnp.uint32),
-                          jnp.zeros((pad,), jnp.uint32)])
-    out = bloom_probe_multi_kernel(jnp.asarray(filts), jnp.asarray(meta),
+    blocks = 1 << max(-(-n // block) - 1, 0).bit_length()
+    kp = np.zeros(blocks * block, np.uint32)
+    kp[:n] = np.asarray(keys, np.uint32)
+    meta = np.concatenate([meta, reciprocal(meta[:, :1])], axis=1)
+    out = bloom_probe_multi_kernel(jnp.asarray(filts), meta,
                                    kp, k_max=int(meta[:, 1].max()),
                                    block=block, interpret=interpret)
-    return np.asarray(out[:, :n]).astype(bool)
+    return np.asarray(out)[:, :n].astype(bool)

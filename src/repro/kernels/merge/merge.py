@@ -1,18 +1,30 @@
-"""Pallas TPU kernel: two-way sorted merge via merge-path partitioning.
+"""Pallas TPU kernel: pairwise sorted merge via merge-path partitioning.
 
 This is the compaction inner loop the paper's schedulers meter out I/O to.
 The CPU/GPU idiom (an iterator heap) is scalar and branchy; the TPU
-adaptation splits the output into fixed-size blocks whose input windows
-are located by a *merge-path* co-rank search (done once, vectorized, in
-ops.py) and merges each window pair with a data-parallel bitonic merge
-network — pure VPU compare/exchange ops, no data-dependent control flow.
+adaptation splits each pair's output into fixed-size blocks of ``block``
+entries whose input windows are located by a *merge-path* co-rank search
+(done once, vectorized, in ops.py) and merges each window pair with
+bitonic compare-exchange networks — pure VPU selects plus lane/sublane
+rolls, no data-dependent control flow.
 
-Grid: one step per output block.  The co-rank partitions arrive as scalar
-prefetch (SMEM) so each step dynamically slices its input windows; the
-padded runs carry a +inf-equivalent sentinel tail so window loads never
-run out of bounds.  Ties between runs resolve to run A (the *newer* LSM
-component), which makes the downstream newest-wins dedup a pure
-adjacent-key mask.
+Layout: every run lives in HBM as ``(rows, 128)`` int32 tiles (keys are
+stored order-preserving as int32, see ``ops``).  A grid step copies, by
+DMA, the ``2 * block`` entries starting at the 128-aligned row that holds
+its co-rank: the chip can only copy whole rows at a data-dependent
+offset.  The ``off = corank % 128`` entries in front of the co-rank
+belong to earlier blocks; they are masked to the +inf sentinel, which
+leaves each window a bitonic sequence.  A bitonic half-cleaner then
+keeps the window's ``block`` smallest entries and a bitonic merge sorts
+them (run A ascending, run B descending), so no reversal or dynamic
+shift is ever needed; one more half-cleaner + merge of the two sorted
+halves yields the block.  Everything compares (key, age)
+lexicographically: ages are original run indices (smaller = newer), so
+equal keys from different runs stay totally ordered and the downstream
+newest-wins dedup is a pure adjacent-key mask.
+
+Grid: ``(pairs, blocks)`` — one launch merges every adjacent run pair of
+a tournament round.  The co-ranks arrive as scalar prefetch (SMEM).
 """
 from __future__ import annotations
 
@@ -20,177 +32,132 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _sentinel(dtype: jnp.dtype):
-    if jnp.issubdtype(dtype, jnp.floating):
-        return jnp.array(jnp.inf, dtype)
-    return jnp.array(jnp.iinfo(dtype).max, dtype)
-
-
-def _cmp_swap(k0, k1, s0, s1, *payloads):
-    """Compare-exchange on (key, src) lexicographic order; src breaks ties
-    toward run A (src=0, the newer component)."""
-    swap = (k0 > k1) | ((k0 == k1) & (s0 > s1))
-    out_k = (jnp.where(swap, k1, k0), jnp.where(swap, k0, k1))
-    out_s = (jnp.where(swap, s1, s0), jnp.where(swap, s0, s1))
-    outs = []
-    for (p0, p1) in payloads:
-        outs.append((jnp.where(swap, p1, p0), jnp.where(swap, p0, p1)))
-    return out_k, out_s, outs
+LANES = 128
+#: the smallest block: one (8, 128) int32 tile
+TILE = 8 * LANES
+KEY_PAD = jnp.iinfo(jnp.int32).max      # sentinel key (stored form)
+AGE_PAD = jnp.iinfo(jnp.int32).max      # sentinel age (oldest possible)
 
 
-def _bitonic_merge(keys, srcs, payloads):
-    """Merge two sorted halves of a 2S vector (ascending), stable on src."""
-    n = keys.shape[0]
-    half = n // 2
-    # reverse the second half -> single bitonic sequence
-    rev = lambda x: jnp.concatenate([x[:half], x[half:][::-1]])
-    keys, srcs = rev(keys), rev(srcs)
-    payloads = [rev(p) for p in payloads]
-    stride = half
-    while stride >= 1:
-        shape = (-1, 2, stride)
-        k = keys.reshape(shape)
-        s = srcs.reshape(shape)
-        ps = [p.reshape(shape) for p in payloads]
-        (k0, k1), (s0, s1), pout = _cmp_swap(
-            k[:, 0], k[:, 1], s[:, 0], s[:, 1],
-            *[(p[:, 0], p[:, 1]) for p in ps])
-        keys = jnp.stack([k0, k1], axis=1).reshape(n)
-        srcs = jnp.stack([s0, s1], axis=1).reshape(n)
-        payloads = [jnp.stack([p0, p1], axis=1).reshape(n) for (p0, p1) in pout]
-        stride //= 2
-    return keys, srcs, payloads
+def _less(ka, aa, kb, ab):
+    """(ka, aa) < (kb, ab) in (key, age) lexicographic order."""
+    return (ka < kb) | ((ka == kb) & (aa < ab))
 
 
-def _merge_kernel(parts_ref, ka_ref, va_ref, kb_ref, vb_ref,
-                  ko_ref, vo_ref, so_ref, *, block: int):
-    k = pl.program_id(0)
-    ia = parts_ref[k, 0]
-    ib = parts_ref[k, 1]
-    # next-S-element windows from each run (sentinel tail makes this safe)
-    wka = ka_ref[pl.ds(ia, block)]
-    wva = va_ref[pl.ds(ia, block)]
-    wkb = kb_ref[pl.ds(ib, block)]
-    wvb = vb_ref[pl.ds(ib, block)]
-    keys = jnp.concatenate([wka, wkb])
-    vals = jnp.concatenate([wva, wvb])
-    srcs = jnp.concatenate([jnp.zeros((block,), jnp.int32),
-                            jnp.ones((block,), jnp.int32)])
-    mk, ms, (mv,) = _bitonic_merge(keys, srcs, [vals])
-    ko_ref[...] = mk[:block]
-    vo_ref[...] = mv[:block]
-    so_ref[...] = ms[:block]
+def _half_clean(lo, hi):
+    """Element-wise min of two tiles in (key, age) order: applied to the
+    two halves of a bitonic sequence, the result holds its smaller half
+    (itself bitonic)."""
+    take_hi = _less(hi[0], hi[1], lo[0], lo[1])
+    return tuple(jnp.where(take_hi, h, l) for l, h in zip(lo, hi))
 
 
-def _merge_age_kernel(parts_ref, ka_ref, va_ref, aa_ref, kb_ref, vb_ref,
-                      ab_ref, ko_ref, vo_ref, ao_ref, *, block: int):
-    """Age-carrying variant for the k-way tournament: instead of the
-    synthetic 0/1 src, each element carries its ORIGINAL run index
-    (smaller = newer), loaded from the input.  The compare-exchange order
-    is (key, age) lexicographic, so intermediate tournament runs — which
-    contain duplicate keys from different source runs — stay totally
-    ordered (runs have unique keys, making (key, age) pairs distinct) and
-    the final newest-wins dedup is still a pure adjacent-key mask."""
-    k = pl.program_id(0)
-    ia = parts_ref[k, 0]
-    ib = parts_ref[k, 1]
-    wka = ka_ref[pl.ds(ia, block)]
-    wva = va_ref[pl.ds(ia, block)]
-    waa = aa_ref[pl.ds(ia, block)]
-    wkb = kb_ref[pl.ds(ib, block)]
-    wvb = vb_ref[pl.ds(ib, block)]
-    wab = ab_ref[pl.ds(ib, block)]
-    keys = jnp.concatenate([wka, wkb])
-    vals = jnp.concatenate([wva, wvb])
-    ages = jnp.concatenate([waa, wab])
-    mk, ma, (mv,) = _bitonic_merge(keys, ages, [vals])
-    ko_ref[...] = mk[:block]
-    vo_ref[...] = mv[:block]
-    ao_ref[...] = ma[:block]
+def _bitonic_merge(xs, descending: bool):
+    """Sort a bitonic ``(rows, 128)`` tile triple (key, age, val) whose
+    element order is row-major.  Stage ``d`` compare-exchanges element
+    ``e`` with ``e ^ d``; the partner is fetched with two rolls and a
+    select on where a rolled iota landed, which is independent of the
+    roll's direction convention."""
+    rows = xs[0].shape[0]
+    d = rows * LANES // 2
+    while d >= 1:
+        if d < LANES:
+            axis, n, dist = 1, LANES, d
+        else:
+            axis, n, dist = 0, rows, d // LANES
+        iota = lax.broadcasted_iota(jnp.int32, (rows, LANES), axis)
+        fwd = pltpu.roll(iota, dist, axis) == (iota ^ dist)
+        # the lower element of a pair keeps the min (ascending)
+        upper = ((iota & dist) == 0) if descending else ((iota & dist) != 0)
+        part = [jnp.where(fwd, pltpu.roll(x, dist, axis),
+                          pltpu.roll(x, n - dist, axis)) for x in xs]
+        p_less = _less(part[0], part[1], xs[0], xs[1])
+        s_less = _less(xs[0], xs[1], part[0], part[1])
+        take = (upper & s_less) | (~upper & p_less)
+        xs = tuple(jnp.where(take, p, x) for p, x in zip(part, xs))
+        d //= 2
+    return xs
+
+
+def _merge_kernel(parts_ref, k_hbm, a_hbm, v_hbm, ko_ref, ao_ref, vo_ref,
+                  buf, sem, *, block: int, blocks: int):
+    p = pl.program_id(0)
+    b = pl.program_id(1)
+    base = 2 * (p * blocks + b)
+    starts = (parts_ref[base], parts_ref[base + 1])
+    wrows = 2 * block // LANES
+    copies = []
+    for side in range(2):
+        row0 = starts[side] // LANES
+        for j, src in enumerate((k_hbm, a_hbm, v_hbm)):
+            cp = pltpu.make_async_copy(
+                src.at[2 * p + side, pl.ds(row0, wrows)],
+                buf.at[side, j], sem.at[side, j])
+            cp.start()
+            copies.append(cp)
+    for cp in copies:
+        cp.wait()
+    pos = lax.broadcasted_iota(jnp.int32, (wrows, LANES), 0) * LANES + \
+        lax.broadcasted_iota(jnp.int32, (wrows, LANES), 1)
+    srows = block // LANES
+    halves = []
+    for side in range(2):
+        # entries in front of the co-rank belong to earlier blocks: +inf
+        dead = pos < starts[side] % LANES
+        w = (jnp.where(dead, KEY_PAD, buf[side, 0]),
+             jnp.where(dead, AGE_PAD, buf[side, 1]),
+             jnp.where(dead, 0, buf[side, 2]))
+        low = _half_clean(tuple(x[:srows] for x in w),
+                          tuple(x[srows:] for x in w))
+        halves.append(_bitonic_merge(low, descending=side == 1))
+    out = _bitonic_merge(_half_clean(*halves), descending=False)
+    ko_ref[...] = out[0]
+    ao_ref[...] = out[1]
+    vo_ref[...] = out[2]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def merge_path_merge(keys_a, vals_a, keys_b, vals_b, parts,
-                     block: int = 256, interpret: bool = True):
-    """Merge two sorted (key, value) runs.
+def merge_path_merge_age(keys, ages, vals, parts, block: int = TILE,
+                         interpret: bool = False):
+    """Merge adjacent run pairs ``(2p, 2p + 1)`` of a stacked round.
 
-    ``parts``: (g+1, 2) int32 co-rank table from ``ops.merge_partitions``;
-    inputs must already carry a ``block``-length sentinel tail.  Returns
-    (keys, values, src) of length g*block; entries beyond len(a)+len(b)
-    are sentinels.
+    ``keys``/``ages``/``vals`` are ``(runs, rows, 128)`` int32 with each
+    run's valid prefix (key, age)-sorted and a sentinel tail of at least
+    ``2 * block`` entries; every age of run ``2p`` must be smaller than
+    every age of run ``2p + 1`` (the tournament pairs adjacent
+    newest-first groups, which guarantees this).  ``parts`` is the flat
+    ``(pairs * blocks * 2,)`` co-rank table from ``ops``, where
+    ``blocks = rows * 128 * 2 / block - 2``.  Returns three
+    ``(pairs, blocks * block / 128, 128)`` arrays: each pair's merged
+    run followed by a sentinel tail of ``2 * block`` entries.
     """
-    g = parts.shape[0] - 1
-    out_len = g * block
-    kdt, vdt = keys_a.dtype, vals_a.dtype
+    if block % TILE:
+        raise ValueError(f"block must be a multiple of {TILE}")
+    runs, rows, _ = keys.shape
+    pairs = runs // 2
+    blocks = 2 * rows * LANES // block - 2
+    out_rows = blocks * block // LANES
+    out_spec = pl.BlockSpec((None, block // LANES, LANES),
+                            lambda p, b, parts: (p, b, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec(keys_a.shape, lambda k, parts: (0,)),
-            pl.BlockSpec(vals_a.shape, lambda k, parts: (0,)),
-            pl.BlockSpec(keys_b.shape, lambda k, parts: (0,)),
-            pl.BlockSpec(vals_b.shape, lambda k, parts: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda k, parts: (k,)),
-            pl.BlockSpec((block,), lambda k, parts: (k,)),
-            pl.BlockSpec((block,), lambda k, parts: (k,)),
+        grid=(pairs, blocks),
+        in_specs=[any_spec] * 3,
+        out_specs=[out_spec] * 3,
+        scratch_shapes=[
+            pltpu.VMEM((2, 3, 2 * block // LANES, LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((2, 3)),
         ],
     )
+    shape = jax.ShapeDtypeStruct((pairs, out_rows, LANES), jnp.int32)
     return pl.pallas_call(
-        functools.partial(_merge_kernel, block=block),
+        functools.partial(_merge_kernel, block=block, blocks=blocks),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((out_len,), kdt),
-            jax.ShapeDtypeStruct((out_len,), vdt),
-            jax.ShapeDtypeStruct((out_len,), jnp.int32),
-        ],
+        out_shape=[shape] * 3,
         interpret=interpret,
-    )(parts, keys_a, vals_a, keys_b, vals_b)
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def merge_path_merge_age(keys_a, vals_a, age_a, keys_b, vals_b, age_b,
-                         parts, block: int = 256, interpret: bool = True):
-    """Merge two (key, value, age)-sorted runs; ages (original run
-    indices, smaller = newer) replace the synthetic 0/1 src as the
-    tie-breaking payload.  Every age in run A must be smaller than every
-    age in run B (the tournament pairs adjacent newest-first groups, which
-    guarantees this), so the co-rank table from ``ops.merge_partitions``
-    — whose tie rule sends equal keys to run A — stays exact.  Returns
-    (keys, values, ages) of length g*block; entries beyond
-    len(a)+len(b) are sentinels."""
-    g = parts.shape[0] - 1
-    out_len = g * block
-    kdt, vdt = keys_a.dtype, vals_a.dtype
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec(keys_a.shape, lambda k, parts: (0,)),
-            pl.BlockSpec(vals_a.shape, lambda k, parts: (0,)),
-            pl.BlockSpec(age_a.shape, lambda k, parts: (0,)),
-            pl.BlockSpec(keys_b.shape, lambda k, parts: (0,)),
-            pl.BlockSpec(vals_b.shape, lambda k, parts: (0,)),
-            pl.BlockSpec(age_b.shape, lambda k, parts: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda k, parts: (k,)),
-            pl.BlockSpec((block,), lambda k, parts: (k,)),
-            pl.BlockSpec((block,), lambda k, parts: (k,)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_merge_age_kernel, block=block),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((out_len,), kdt),
-            jax.ShapeDtypeStruct((out_len,), vdt),
-            jax.ShapeDtypeStruct((out_len,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(parts, keys_a, vals_a, age_a, keys_b, vals_b, age_b)
+    )(parts, keys, ages, vals)

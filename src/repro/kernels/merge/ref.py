@@ -4,7 +4,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from .merge import _sentinel
 
 
 def merge_sorted_ref(keys_a, vals_a, keys_b, vals_b):

@@ -144,7 +144,8 @@ def test_input_specs_cover_all_cells():
 def test_effective_microbatches_divisibility():
     from repro.train.steps import effective_microbatches
     import jax
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for arch in ARCHS:
         cfg = get_config(arch)
         for gb in (256, 32, 8):

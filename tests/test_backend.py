@@ -26,9 +26,11 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from repro.core.backend import (COMPILED, HOST, INTERPRET, ExecBackend,
-                                compiled_supported, load_calibration,
-                                merge_kway_host, write_calibration)
+from repro.core import backend as backend_mod
+from repro.core.backend import (COMPILED, HOST, INTERPRET, OPS, ExecBackend,
+                                compiled_supported, device_kind,
+                                load_calibration, merge_kway_host,
+                                write_calibration)
 from repro.core.constraints import NoConstraint
 from repro.core.engine import LSMEngine
 from repro.core.fleet import LSMFleet
@@ -125,8 +127,7 @@ def test_window_merge_composes_and_matches_host(mode):
     rng = np.random.default_rng(4)
     runs = _runs(rng, k=4, n=700)
     be = ExecBackend(mode=mode, merge_block=64)
-    want_k, want_v, _ = be.merge_kway(
-        runs, runs_dev=lambda: runs)
+    want_k, want_v, _ = be.merge_kway(runs)
     host_k, host_v = merge_kway_host(runs)
     assert np.array_equal(want_k, host_k), mode
     assert np.array_equal(want_v, host_v), mode
@@ -136,8 +137,7 @@ def test_window_merge_composes_and_matches_host(mode):
     for lo, hi in zip(cuts[:-1], cuts[1:]):
         starts = [int(np.searchsorted(k, np.uint32(lo))) for k, _ in runs]
         stops = [int(np.searchsorted(k, np.uint32(hi))) for k, _ in runs]
-        wk, wv, _ = be.merge_kway_window(runs, starts, stops,
-                                         runs_dev=lambda: runs)
+        wk, wv, _ = be.merge_kway_window(runs, starts, stops)
         got_k.append(wk)
         got_v.append(wv)
     assert np.array_equal(np.concatenate(got_k), want_k), mode
@@ -163,8 +163,8 @@ def test_scan_merge_drops_tombstones_identically(mode):
 
 
 # ----------------------------------------------------- dispatch decisions
-def _cal_table():
-    return {"ops": {
+def _cal_table(kind=None):
+    return {"device_kind": kind or device_kind(), "ops": {
         "merge_kway": {"sizes": [1000, 100000],
                        "best": [HOST, COMPILED],
                        "ms": {HOST: [0.1, 50.0],
@@ -235,6 +235,52 @@ def test_committed_calibration_artifact_loads():
         assert be.decide(op, 4096) in (HOST, INTERPRET, COMPILED)
 
 
+def test_calibration_from_another_device_is_not_applied():
+    """A table measured on another kind of device never drives dispatch:
+    the backend drops it and decides by its built-in default."""
+    be = ExecBackend(mode="auto", calibration=_cal_table("TPU v5 lite"))
+    assert be.calibration is None
+    default = COMPILED if compiled_supported() else HOST
+    assert be.decide("merge_kway", 500) == default
+    assert be.decide("merge_kway", 200_000) == default
+    assert ExecBackend(mode="auto",
+                       calibration=_cal_table()).calibration is not None
+
+
+@pytest.fixture
+def reported_tpu(monkeypatch):
+    """Make JAX report a TPU default backend (the compiled-support probe
+    cache is cleared around it, so no other test sees the verdict)."""
+    monkeypatch.setattr(backend_mod.jax, "default_backend", lambda: "tpu")
+    compiled_supported.cache_clear()
+    yield
+    compiled_supported.cache_clear()
+
+
+@pytest.mark.parametrize("size", [1, 500, 50_000, 10 ** 9])
+def test_decide_on_tpu_is_always_compiled(reported_tpu, size):
+    """On a TPU every data-plane decision of an unforced backend is
+    compiled, whatever the calibration table says — including the
+    engine's default backend."""
+    cal = _cal_table()
+    for op in OPS:
+        cal["ops"].setdefault(op, {"sizes": [1], "best": [HOST],
+                                   "ms": {HOST: [0.1]}})
+    eng = _mk_engine("tiering", None)
+    for be in (ExecBackend(mode="auto", calibration=cal), eng.backend):
+        for op in OPS:
+            assert be.decide(op, size) == COMPILED, (op, size)
+
+
+def test_compiled_supported_propagates_lowering_errors(reported_tpu):
+    """Where a chip is reported, a kernel the backend cannot lower must
+    raise out of ``compiled_supported`` — never read as 'unsupported'
+    and quietly move the data plane to the host."""
+    with pytest.raises(Exception) as err:
+        compiled_supported()
+    assert not isinstance(err.value, AssertionError)
+
+
 def test_compiled_mode_raises_when_unsupported():
     if compiled_supported():
         pytest.skip("compiled Pallas available here")
@@ -263,10 +309,16 @@ def test_from_legacy_reproduces_old_dispatch():
 
 
 def test_engine_legacy_flags_are_backend_views():
-    eng = _mk_engine("tiering", None)     # defaults: kernels, interpret
-    assert eng.use_kernels is True
-    assert eng.interpret is True
-    assert eng.scan_use_kernels is False  # auto: kernel only if compiled
+    eng = _mk_engine("tiering", None)     # default: auto dispatch
+    assert eng.backend.mode == "auto" and not eng.backend._forced
+    assert eng.use_kernels is (eng.backend.decide("merge_kway", 1 << 20)
+                               != HOST)
+    legacy = LSMEngine(TieringPolicy(3, 64, 2048), FairScheduler(),
+                       NoConstraint(), memtable_entries=64,
+                       unique_keys=2048, use_kernels=True, interpret=True)
+    assert legacy.use_kernels is True
+    assert legacy.backend.decide("probe_multi", 1) == INTERPRET
+    assert legacy.scan_use_kernels is False  # auto: kernel only if compiled
     eng2 = LSMEngine(TieringPolicy(3, 64, 2048), FairScheduler(),
                      NoConstraint(), memtable_entries=64,
                      unique_keys=2048, use_kernels=False)

@@ -3,6 +3,7 @@ axes, compression, and an 8-device end-to-end subprocess check (device
 count must be set before jax initializes, hence the subprocess)."""
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -15,13 +16,22 @@ import jax
 import jax.numpy as jnp
 
 SRC = str(Path(__file__).resolve().parent.parent / "src")
+AUTO2 = (jax.sharding.AxisType.Auto,) * 2
+
+
+def _child_env() -> dict:
+    """A clean child environment on the CPU backend: the child sets its
+    own virtual device count and must not load the TPU library."""
+    return {"PYTHONPATH": SRC,
+            "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+            "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"}
 
 
 # ----------------------------------------------------------- rule logic
 def test_spec_divisibility_fallback():
     from repro.distributed.sharding import spec_for
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     rules = {"q_heads": ("model",), "embed": ("data",)}
     # trivially divisible by 1
     assert spec_for(mesh, rules, (9, 64), ("q_heads", "embed")) == \
@@ -31,7 +41,7 @@ def test_spec_divisibility_fallback():
 def test_spec_axis_used_once():
     from repro.distributed.sharding import spec_for
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     rules = {"experts": ("model",), "ffn": ("model",), "embed": ("data",)}
     # model axis consumed by experts; ffn must stay unsharded
     spec = spec_for(mesh, rules, (16, 4, 128), ("experts", "embed", "ffn"))
@@ -84,12 +94,13 @@ _SUBPROC = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    AUTO2 = (jax.sharding.AxisType.Auto,) * 2
     from repro.configs import get_smoke
     from repro.train.steps import (init_train_state, make_train_step,
                                    batch_shardings, input_specs)
     from repro.distributed.sharding import default_rules
     cfg = get_smoke("smollm-135m")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO2)
     rules = default_rules(mesh)
     step_fn, shardings, _ = make_train_step(cfg, mesh)
     state = init_train_state(cfg, jax.random.PRNGKey(0))
@@ -109,7 +120,7 @@ _SUBPROC = textwrap.dedent("""
     store = LSMCheckpointStore(tempfile.mkdtemp())
     host = jax.tree.map(np.asarray, state)
     store.put_delta(0, flatten_state(host))
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
     restored, _ = reshard_restore(store, mesh2, train_state_axes(cfg))
     step_fn2, sh2, _ = make_train_step(cfg, mesh2)
     with mesh2:
@@ -124,8 +135,7 @@ _SUBPROC = textwrap.dedent("""
 def test_multidevice_train_and_elastic_reshard():
     r = subprocess.run([sys.executable, "-c", _SUBPROC],
                        capture_output=True, text=True, timeout=600,
-                       env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env=_child_env())
     assert "SUBPROC_OK" in r.stdout, r.stdout + r.stderr
 
 
@@ -134,6 +144,7 @@ _SUBPROC_MOE = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    AUTO2 = (jax.sharding.AxisType.Auto,) * 2
     from repro.configs import get_smoke
     from repro.models import init_params, train_loss
     from repro.distributed.sharding import default_rules, make_constrainer
@@ -147,7 +158,7 @@ _SUBPROC_MOE = textwrap.dedent("""
     ref_loss, _ = jax.jit(lambda p, b: train_loss(cfg, p, b))(params, batch)
     # expert-parallel shard_map path on a (4, 2) mesh (model axis = 2
     # divides the 4 smoke experts)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO2)
     sh = make_constrainer(mesh, default_rules(mesh))
     with mesh:
         ep_loss, _ = jax.jit(lambda p, b: train_loss(cfg, p, b, sh=sh))(
@@ -164,6 +175,5 @@ def test_moe_expert_parallel_matches_single_device():
     (dropless capacity so routing is identical)."""
     r = subprocess.run([sys.executable, "-c", _SUBPROC_MOE],
                        capture_output=True, text=True, timeout=600,
-                       env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env=_child_env())
     assert "MOE_EP_OK" in r.stdout, r.stdout + r.stderr

@@ -209,9 +209,18 @@ def test_leveling_concurrent_merges_stay_age_adjacent():
 
 # --------------------------------------------------- interpret plumbing
 def test_interpret_flag_plumbed_to_tables():
-    eng = _mk("tiering", memtable=32, unique=256)
-    assert eng.interpret is True
-    eng.put_batch(np.arange(32, dtype=np.uint32), np.zeros(32, np.int32))
+    """An explicit ``interpret=True`` reaches every kernel launch the
+    engine makes for its tables (they are screened by the fused probe, so
+    no per-table mode exists), and reads of flushed tables still resolve."""
+    eng = LSMEngine(TieringPolicy(3, 32, 256), GreedyScheduler(),
+                    GlobalConstraint(200), memtable_entries=32,
+                    unique_keys=256, use_kernels=True, interpret=True)
+    for op in ("probe_multi", "merge_kway", "merge_kway_window"):
+        assert eng.backend.decide(op, 1 << 20) == "interpret", op
+    keys = np.arange(32, dtype=np.uint32)
+    eng.put_batch(keys, keys.astype(np.int32) + 1)
     eng._seal_active()
     eng.pump(64)
-    assert all(t.interpret for t in eng.tables.values())
+    assert eng.tables and not len(eng.active)
+    found, vals = eng.get_batch(keys)
+    assert found.all() and np.array_equal(vals, keys.astype(np.int32) + 1)

@@ -36,7 +36,7 @@ def test_merge_sorted_sweep(na, nb, block, dtype):
     vb = rng.integers(0, 1 << 30, nb).astype(np.int32)
     mk, mv, ms, valid = merge_sorted(jnp.asarray(ka), jnp.asarray(va),
                                      jnp.asarray(kb), jnp.asarray(vb),
-                                     block=block)
+                                     block=block, interpret=True)
     rk, rv, rs = merge_sorted_ref(jnp.asarray(ka), jnp.asarray(va),
                                   jnp.asarray(kb), jnp.asarray(vb))
     assert valid == na + nb
@@ -56,7 +56,7 @@ def test_merge_dedup_matches_dict_oracle(na, nb):
     vb = rng.integers(0, 1 << 30, nb).astype(np.int32)
     mk, mv, keep, valid = merge_dedup(jnp.asarray(ka), jnp.asarray(va),
                                       jnp.asarray(kb), jnp.asarray(vb),
-                                      block=128)
+                                      block=128, interpret=True)
     keep = np.array(keep)
     keep[valid:] = False
     rk, rv = merge_dedup_ref(ka, va, kb, vb)
@@ -84,7 +84,7 @@ def _mk_runs(rng, sizes, key_space):
 def test_merge_dedup_kway_matches_dict_oracle(sizes, block):
     rng = np.random.default_rng(sum(sizes))
     runs = _mk_runs(rng, sizes, max(sizes) * 2 + 1)   # heavy key overlap
-    mk, mv = merge_dedup_kway(runs, block=block)
+    mk, mv = merge_dedup_kway(runs, block=block, interpret=True)
     rk, rv = merge_dedup_kway_ref(runs)
     np.testing.assert_array_equal(np.asarray(mk), rk)
     np.testing.assert_array_equal(np.asarray(mv), rv)
@@ -95,12 +95,12 @@ def test_merge_dedup_kway_equals_pairwise_fold():
     (oldest -> newest, newer run as A) it replaces in the engine."""
     rng = np.random.default_rng(9)
     runs = _mk_runs(rng, (120, 90, 255, 33, 64, 128), 400)
-    mk, mv = merge_dedup_kway(runs, block=64)
+    mk, mv = merge_dedup_kway(runs, block=64, interpret=True)
 
     fk, fv = (jnp.asarray(runs[-1][0]), jnp.asarray(runs[-1][1]))
     for ks, vs in reversed(runs[:-1]):     # fold oldest->newest, newer = A
         k2, v2, keep, valid = merge_dedup(jnp.asarray(ks), jnp.asarray(vs),
-                                          fk, fv, block=64)
+                                          fk, fv, block=64, interpret=True)
         keep = np.array(keep)
         keep[valid:] = False
         fk, fv = jnp.asarray(np.asarray(k2)[keep]), \
@@ -117,7 +117,7 @@ def test_merge_dedup_kway_duplicate_heavy():
     ks = np.sort(rng.choice(2048, 300, replace=False)).astype(np.uint32)
     runs = [(ks, rng.integers(0, 1 << 30, 300).astype(np.int32))
             for _ in range(5)]
-    mk, mv = merge_dedup_kway(runs, block=64)
+    mk, mv = merge_dedup_kway(runs, block=64, interpret=True)
     np.testing.assert_array_equal(np.asarray(mk), ks)
     np.testing.assert_array_equal(np.asarray(mv), runs[0][1])
 
@@ -133,15 +133,17 @@ def test_bloom_sweep(n, fpr):
     absent = np.setdiff1d(
         rng.choice(1 << 24, 3 * n, replace=False).astype(np.uint32), keys)
     for qs in (keys, absent[:n]):
-        got = np.asarray(bloom_probe(filt, jnp.asarray(qs), n_bits, k))
+        got = np.asarray(bloom_probe(filt, jnp.asarray(qs), n_bits, k,
+                                     interpret=True))
         bits = bloom_build_ref(keys, n_bits, k)
         want = bloom_probe_ref(bits, qs, n_bits, k)
         np.testing.assert_array_equal(got, want)
     # no false negatives; fp rate near target
-    present = np.asarray(bloom_probe(filt, jnp.asarray(keys), n_bits, k))
+    present = np.asarray(bloom_probe(filt, jnp.asarray(keys), n_bits, k,
+                                     interpret=True))
     assert present.all()
     fp = np.mean(np.asarray(bloom_probe(filt, jnp.asarray(absent[:2000]),
-                                        n_bits, k)))
+                                        n_bits, k, interpret=True)))
     assert fp <= max(3 * fpr, 0.02)
 
 
@@ -162,17 +164,20 @@ def test_bloom_probe_multi_equals_per_table():
                                 [t[3] for t in tables])
     assert filts.shape[1] == max(t[1].shape[0] for t in tables)
     qs = rng.integers(0, 1 << 22, 513, dtype=np.uint32)   # non-block-aligned
-    multi = bloom_probe_multi(filts, meta, qs)
+    multi = bloom_probe_multi(filts, meta, qs, interpret=True)
     assert multi.shape == (len(tables), len(qs))
     for i, (keys, filt, n_bits, k) in enumerate(tables):
-        single = np.asarray(bloom_probe(filt, jnp.asarray(qs), n_bits, k))
+        single = np.asarray(bloom_probe(filt, jnp.asarray(qs), n_bits, k,
+                                        interpret=True))
         np.testing.assert_array_equal(multi[i], single)
-        own = bloom_probe_multi(filts, meta, keys)
+        own = bloom_probe_multi(filts, meta, keys, interpret=True)
         assert own[i].all(), f"false negative in table {i}"
     # degenerate shapes
-    assert bloom_probe_multi(filts[:0], meta[:0], qs).shape == (0, len(qs))
+    assert bloom_probe_multi(filts[:0], meta[:0], qs,
+                             interpret=True).shape == (0, len(qs))
     empty_q = np.empty(0, np.uint32)
-    assert bloom_probe_multi(filts, meta, empty_q).shape == (len(tables), 0)
+    assert bloom_probe_multi(filts, meta, empty_q,
+                             interpret=True).shape == (len(tables), 0)
 
 
 # ------------------------------------------------------------- attention
