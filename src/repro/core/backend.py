@@ -56,7 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.bloom.ops import bloom_probe_multi, bloom_probe_multi_host
+from repro.kernels.bloom.ops import (bloom_probe_multi,
+                                     bloom_probe_multi_host, probe_batch)
 from repro.kernels.merge.ops import merge_dedup_kway
 
 from .memtable import drop_tombstones
@@ -285,19 +286,25 @@ class ExecBackend:
 
     # -------------------------------------------------------- entry points
     def probe_multi(self, filts, meta, keys,
-                    filts_host: Optional[np.ndarray] = None) -> np.ndarray:
-        """Fused multi-table Bloom probe: (tables, keys) maybe-present
-        matrix.  Host mode runs the vectorized numpy probe over
-        ``filts_host`` (the filter stack's host mirror); kernel modes
-        launch the Pallas probe over the device stack."""
+                    filts_host: Optional[np.ndarray] = None
+                    ) -> tuple[np.ndarray, int]:
+        """Fused multi-table Bloom probe.  Returns the (tables, keys)
+        maybe-present matrix and the stack rows x keys the launch
+        screened, padding included.  Host mode runs the vectorized numpy
+        probe over ``filts_host`` (the filter stack's host mirror), keys
+        unpadded; kernel modes launch the Pallas probe over the device
+        stack and the batch padded to ``probe_batch`` keys."""
         n_rows = int(filts.shape[0]) if filts is not None \
             else int(filts_host.shape[0])
-        mode = self.decide("probe_multi", n_rows * len(keys))
+        n = len(keys)
+        mode = self.decide("probe_multi", n_rows * n)
         if mode == HOST and filts_host is not None:
-            return bloom_probe_multi_host(filts_host, np.asarray(meta),
-                                          np.asarray(keys, np.uint32))
-        return bloom_probe_multi(filts, meta, keys,
-                                 interpret=mode == INTERPRET)
+            return (bloom_probe_multi_host(filts_host, np.asarray(meta),
+                                           np.asarray(keys, np.uint32)),
+                    n_rows * n)
+        return (bloom_probe_multi(filts, meta, keys,
+                                  interpret=mode == INTERPRET),
+                n_rows * probe_batch(n) if n_rows and n else 0)
 
     def _kernel_merge(self, runs, mode: str, drop_value: Optional[int]):
         return (*merge_dedup_kway(runs, block=self.merge_block,

@@ -99,6 +99,14 @@ Backend / dispatch: every launch routes through the group's ONE
 calibration artifact; the three legacy booleans map to forced modes via
 ``ExecBackend.from_legacy`` and are exposed read-only).
 
+Spans and counters: every get/put/scan/pump call opens an ``lsm.*``
+span and one child span per step (``core/tracing.py``), recorded only
+while a profiler trace runs; the wait for the group lock is ``lsm.lock``.
+The counters are the trees' ``stats``; beside the flush, merge and WAL
+counts, each probe launch adds ``probe_cells`` (stack rows x keys it
+screened, padding included) and ``probe_live_cells`` (the (row, key)
+pairs whose table's key range holds the key).
+
 Thread safety: every foreground entry point and the background plane
 take the GROUP's reentrant lock internally; ``lock()`` exposes it for
 compound atomicity.  ``scan_range`` releases it for the merge itself
@@ -174,6 +182,7 @@ answer.  ``health()`` exposes the fault-plane counters.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -197,6 +206,7 @@ from .policies import MergePolicy
 from .scheduler import (FairScheduler, MergeScheduler,
                         apportion_largest_remainder)
 from .sstable import SSTable
+from .tracing import span
 
 ENTRY_BYTES = 1024  # paper's 1 KB records: 1 entry == 1 KB of I/O budget
 
@@ -244,6 +254,10 @@ class _FilterStack:
     (n_bits=128, k=1) metadata so they never inflate the probe's static
     ``k_max``; their stale word content is only reachable through a
     stale (raced, uncached) view's ``stack_slot``.
+
+    ``lo``/``hi`` hold each row's table's first and last key (a free
+    row's ``[1, 0]`` holds none), so ``live_cells`` can count the rows
+    a probe needed without walking the tables.
     """
 
     def __init__(self):
@@ -252,6 +266,8 @@ class _FilterStack:
                                                      # stack — the backend's
                                                      # HOST probe operand
         self.meta = np.zeros((0, 2), np.uint32)      # host (cap, 2)
+        self.lo = np.ones(0, np.uint32)              # host (cap,) key
+        self.hi = np.zeros(0, np.uint32)             # range of each row
         self.slots: dict[int, int] = {}              # component cid -> row
         self.free: list[int] = []
         self._add: dict[int, SSTable] = {}           # pending, cid-keyed
@@ -282,11 +298,14 @@ class _FilterStack:
         self.meta = np.zeros((cap, 2), np.uint32)
         self.meta[:, 0] = 128
         self.meta[:, 1] = 1
+        self.lo = np.ones(cap, np.uint32)
+        self.hi = np.zeros(cap, np.uint32)
         self.slots = {}
         for i, t in enumerate(tables):
             w = t.bloom_host()
             stk[i, :w.shape[0]] = w
             self.meta[i] = (t.n_bits, t.k_hashes)
+            self.lo[i], self.hi[i] = _key_range(t)
             self.slots[t.component.cid] = i
             t.stack_slot = i
         self.free = list(range(len(tables), cap))
@@ -311,6 +330,7 @@ class _FilterStack:
             if row is not None:
                 self.free.append(row)
                 self.meta[row] = (128, 1)
+                self.lo[row], self.hi[row] = 1, 0
         self._remove.clear()
         if self._add:
             adds = list(self._add.values())
@@ -332,12 +352,28 @@ class _FilterStack:
                                                   # (HOST probe operand)
                                                   # in lockstep
                 self.meta[row] = (t.n_bits, t.k_hashes)
+                self.lo[row], self.hi[row] = _key_range(t)
                 self.slots[t.component.cid] = row
                 t.stack_slot = row
             self._add.clear()
         elif self.cap > 8 and 4 * len(self.slots) < self.cap:
             self._rebuild(tables)
         return self.filts, self.meta
+
+    def live_cells(self, keys) -> int:
+        """Rows x keys a probe of ``keys`` needs: the (row, key) pairs
+        whose table's key range holds the key, the only rows that can
+        answer it.  One sort and two searches, whatever the rows."""
+        sk = np.sort(np.asarray(keys, np.uint32))
+        held = (np.searchsorted(sk, self.hi, "right")
+                - np.searchsorted(sk, self.lo, "left"))
+        return int(np.maximum(held, 0).sum())
+
+
+def _key_range(t: SSTable) -> tuple[int, int]:
+    """A table's first and last key; an empty table's ``(1, 0)`` holds
+    none."""
+    return (int(t.keys_np[0]), int(t.keys_np[-1])) if len(t) else (1, 0)
 
 
 @dataclass
@@ -460,7 +496,8 @@ class LSMTree:
                       "merges": 0, "merge_bytes": 0, "merge_touched": 0,
                       "lookups": 0, "bloom_skips": 0,
                       "deletes": 0, "replayed": 0, "tombstones_dropped": 0,
-                      "flush_bytes": 0, "logical_bytes": 0}
+                      "flush_bytes": 0, "logical_bytes": 0,
+                      "probe_cells": 0, "probe_live_cells": 0}
 
     # ------------------------------------------------------------ memtables
     def seal_active(self, next_start_lsn: Optional[int] = None) -> None:
@@ -574,37 +611,45 @@ class LSMTree:
         self.stats["lookups"] += q
         resolved = np.zeros(q, bool)
         vals = np.zeros(q, np.int32)
-        for mt in (self.active, *reversed(self.sealed)):
-            if resolved.all():
-                break
-            f, v = mt.get_batch(keys)
-            new = f & ~resolved
-            vals[new] = v[new]
-            resolved |= new
+        with span("lsm.get.memtables"):
+            for mt in (self.active, *reversed(self.sealed)):
+                if resolved.all():
+                    break
+                f, v = mt.get_batch(keys)
+                new = f & ~resolved
+                vals[new] = v[new]
+                resolved |= new
         if not resolved.all():
-            view = self._read_view()
-            if view.tables:
+            with span("lsm.get.filters"):
+                view = self._read_view()
                 filts, meta = self._view_filters(view)
+            if view.tables:
                 # probe the full stack (capacity rows, <= 2x live
                 # tables); each table's row is its own stack_slot — no
                 # gather.  The backend picks host vs kernel; the host
                 # path probes the stack's host mirror.
-                probed = self.group.backend.probe_multi(
-                    filts, meta, keys, filts_host=self._fstack.filts_np)
-                for table in view.tables:
-                    pend = ~resolved
-                    if not pend.any():
-                        break
-                    maybe_t = probed[table.stack_slot]
-                    cand = pend & maybe_t
-                    self.stats["bloom_skips"] += int((pend & ~maybe_t).sum())
-                    if not cand.any():
-                        continue
-                    idx = np.flatnonzero(cand)
-                    f, v = table.search(keys[idx])
-                    hit = idx[f]
-                    vals[hit] = v[f]
-                    resolved[hit] = True
+                with span("lsm.get.probe"):
+                    probed, screened = self.group.backend.probe_multi(
+                        filts, meta, keys, filts_host=self._fstack.filts_np)
+                self.stats["probe_cells"] += screened
+                self.stats["probe_live_cells"] += \
+                    self._fstack.live_cells(keys)
+                with span("lsm.get.search"):
+                    for table in view.tables:
+                        pend = ~resolved
+                        if not pend.any():
+                            break
+                        maybe_t = probed[table.stack_slot]
+                        cand = pend & maybe_t
+                        self.stats["bloom_skips"] += int(
+                            (pend & ~maybe_t).sum())
+                        if not cand.any():
+                            continue
+                        idx = np.flatnonzero(cand)
+                        f, v = table.search(keys[idx])
+                        hit = idx[f]
+                        vals[hit] = v[f]
+                        resolved[hit] = True
         found = resolved & (vals != TOMBSTONE)
         vals = np.where(found, vals, 0).astype(np.int32)
         return found, vals
@@ -637,20 +682,24 @@ class LSMTree:
         the merge itself runs OUTSIDE it (the captured windows are
         immutable snapshots), so a large scan never extends the pump's
         lock-hold tail."""
-        with self.group._rlock:
-            runs = self._scan_runs(lo, hi)
-        if not runs:
-            return np.empty(0, np.uint32), np.empty(0, np.int32)
-        if len(runs) == 1:
-            # copy: the windows are views into live run storage (sealed
-            # caches / host mirrors), which callers must not alias.
-            # Tombstones are filtered like any other scan result.
-            ks, vs = drop_tombstones(runs[0][0], runs[0][1])
-            return ks.copy(), vs.copy()
-        # the backend fuses tombstone filtering into its merge (kernel:
-        # the compaction mask; host: drop_tombstones on the merged run)
-        return self.group.backend.scan_merge(runs,
-                                             drop_value=int(TOMBSTONE))
+        with span("lsm.scan"):
+            with self.group._locked(), span("lsm.scan.runs"):
+                runs = self._scan_runs(lo, hi)
+            if not runs:
+                return np.empty(0, np.uint32), np.empty(0, np.int32)
+            with span("lsm.scan.merge"):
+                if len(runs) == 1:
+                    # copy: the windows are views into live run storage
+                    # (sealed caches / host mirrors), which callers must
+                    # not alias.  Tombstones are filtered like any other
+                    # scan result.
+                    ks, vs = drop_tombstones(runs[0][0], runs[0][1])
+                    return ks.copy(), vs.copy()
+                # the backend fuses tombstone filtering into its merge
+                # (kernel: the compaction mask; host: drop_tombstones on
+                # the merged run)
+                return self.group.backend.scan_merge(
+                    runs, drop_value=int(TOMBSTONE))
 
     # ------------------------------------------------------- background I/O
     def pump_tree(self, budget_entries: int) -> int:
@@ -666,12 +715,13 @@ class LSMTree:
         spent += repay
         while self.sealed and spent < budget_entries:
             g._fault("pre-flush")
-            mt = self.sealed.pop(0)
-            keys, vals = mt.seal()
-            table = SSTable.build(keys, vals,
-                                  level=self.policy.flush_target_level(),
-                                  created_at=g.now)
-            self._bind_table(table)
+            with span("lsm.pump.flush"):
+                mt = self.sealed.pop(0)
+                keys, vals = mt.seal()
+                table = SSTable.build(
+                    keys, vals, level=self.policy.flush_target_level(),
+                    created_at=g.now)
+                self._bind_table(table)
             self.stats["flushes"] += 1
             self.stats["flush_bytes"] += len(keys) * ENTRY_BYTES
             cost = len(keys)
@@ -698,8 +748,9 @@ class LSMTree:
                     # dispatch through the GROUP so instance-level
                     # instrumentation (tests wrap eng._advance_merge)
                     # sees every tree's merges
-                    spent += g._advance_merge(self.running[op_id],
-                                              quantum)
+                    with span("lsm.pump.merge"):
+                        spent += g._advance_merge(self.running[op_id],
+                                                  quantum)
             assert spent <= budget_entries, \
                 "merge quanta exceeded the pump budget"
         return spent
@@ -1071,7 +1122,8 @@ class LSMTree:
 _STATS_ORDER = ("puts", "stall_events", "flushes", "merges", "merge_bytes",
                 "merge_touched", "lookups", "bloom_skips", "deletes",
                 "replayed", "tombstones_dropped", "wal_entries", "wal_bytes",
-                "wal_syncs", "flush_bytes", "logical_bytes")
+                "wal_syncs", "flush_bytes", "logical_bytes",
+                "probe_cells", "probe_live_cells")
 
 
 class StorageGroup:
@@ -1222,11 +1274,8 @@ class StorageGroup:
         """Returns False when the write must stall (component constraint
         or no free primary memtable slot) — the caller decides to
         retry/queue."""
-        if np.int32(value) == TOMBSTONE:
-            raise ValueError("value -2**31 is reserved (delete tombstone)")
-        with self._rlock:
-            return self._put_batch_locked(np.array([key], np.uint32),
-                                          np.array([value], np.int32)) == 1
+        return self.put_batch(np.array([key], np.uint32),
+                              np.array([value], np.int32)) == 1
 
     def put_batch(self, keys, values) -> int:
         """Bulk admission: admit entries in numpy-slice chunks sized to
@@ -1235,12 +1284,14 @@ class StorageGroup:
         stall.  Each admitted chunk triggers index maintenance (eager:
         old-value probe + stale tombstone + insert; lazy: blind append)
         before the next chunk is considered."""
-        keys = np.asarray(keys, np.uint32)
-        values = np.asarray(values, np.int32)
-        if (values == TOMBSTONE).any():
-            raise ValueError("value -2**31 is reserved (delete tombstone)")
-        with self._rlock:
-            return self._put_batch_locked(keys, values)
+        with span("lsm.put"):
+            keys = np.asarray(keys, np.uint32)
+            values = np.asarray(values, np.int32)
+            if (values == TOMBSTONE).any():
+                raise ValueError(
+                    "value -2**31 is reserved (delete tombstone)")
+            with self._locked():
+                return self._put_batch_locked(keys, values)
 
     def delete(self, key: int) -> bool:
         """Blind delete: admit a TOMBSTONE for ``key`` through the
@@ -1255,10 +1306,11 @@ class StorageGroup:
         """Bulk blind deletes: ``put_batch`` semantics (admit until the
         first stall, returns the admitted count), writing TOMBSTONE
         values."""
-        keys = np.asarray(keys, np.uint32)
-        vals = np.full(len(keys), TOMBSTONE, np.int32)
-        with self._rlock:
-            return self._put_batch_locked(keys, vals, deletes=True)
+        with span("lsm.put"):
+            keys = np.asarray(keys, np.uint32)
+            vals = np.full(len(keys), TOMBSTONE, np.int32)
+            with self._locked():
+                return self._put_batch_locked(keys, vals, deletes=True)
 
     def _put_batch_locked(self, keys, values, deletes: bool = False) -> int:
         primary = self.trees[0]
@@ -1310,7 +1362,8 @@ class StorageGroup:
                 primary.stats["stall_events"] += 1
                 self._health["enospc_stalls"] += 1
                 break
-            took = primary.active.put_batch(chunk_k, chunk_v)
+            with span("lsm.put.memtable"):
+                took = primary.active.put_batch(chunk_k, chunk_v)
             assert took == take, "memtable admitted less than its room"
             n_ok += took
             if self._recovery is not None and \
@@ -1433,7 +1486,8 @@ class StorageGroup:
         if self.wal is None:
             self._lsn += len(keys)
             return base
-        base = self.wal.append(keys, vals, tree=tree)
+        with span("lsm.put.wal"):
+            base = self.wal.append(keys, vals, tree=tree)
         self._lsn = self.wal.end_lsn
         self._wal_stats["wal_entries"] += len(keys)
         self._fault("post-wal-pre-memtable")
@@ -1452,7 +1506,8 @@ class StorageGroup:
         n = self.wal.unsynced_entries
         if n <= 0:
             return
-        self.wal.sync()
+        with span("lsm.wal.sync"):
+            self.wal.sync()
         self._wal_debt += n + self.wal_sync_cost
         self._wal_stats["wal_bytes"] += n * ENTRY_BYTES
         self._wal_stats["wal_syncs"] += 1
@@ -1466,9 +1521,10 @@ class StorageGroup:
         """Primary-tree point reads (see ``LSMTree.get_batch_locked``):
         one fused multi-table Bloom probe behind a newest-first walk with
         early exit.  Thread-safe under the group lock."""
-        keys = np.asarray(keys, np.uint32)
-        with self._rlock:
-            return self.trees[0].get_batch_locked(keys)
+        with span("lsm.get"):
+            keys = np.asarray(keys, np.uint32)
+            with self._locked():
+                return self.trees[0].get_batch_locked(keys)
 
     def _get_batch_locked(self, keys):
         return self.trees[0].get_batch_locked(keys)
@@ -1484,7 +1540,7 @@ class StorageGroup:
         gathers these across shards into ONE flat k-way merge.  The
         returned windows may alias live storage: callers must not write
         through them."""
-        with self._rlock:
+        with self._locked():
             return self.trees[0]._scan_runs(lo, hi)
 
     def scan_range_dict(self, lo: int, hi: int) -> dict[int, int]:
@@ -1559,7 +1615,7 @@ class StorageGroup:
         (largest-remainder apportionment); each tree spends its quantum
         on flushes (strict priority) then merges per its scheduler.
         Returns entries actually charged."""
-        with self._rlock:
+        with span("lsm.pump"), self._locked():
             return self._pump_locked(budget_entries)
 
     def _pump_locked(self, budget_entries: int) -> int:
@@ -1767,6 +1823,17 @@ class StorageGroup:
     _merge_kway_host = staticmethod(merge_kway_host)
 
     # ------------------------------------------------------------------ info
+    @contextlib.contextmanager
+    def _locked(self):
+        """The group lock for one get/put/scan/pump call, the wait for
+        it recorded as the ``lsm.lock`` span."""
+        with span("lsm.lock"):
+            self._rlock.acquire()
+        try:
+            yield
+        finally:
+            self._rlock.release()
+
     def lock(self) -> threading.RLock:
         """The group's reentrant lock (see module docstring): the
         ``BackgroundDriver`` holds it around ``pump``; foreground callers

@@ -118,20 +118,25 @@ def bloom_probe_multi_host(filts_np: np.ndarray, meta: np.ndarray,
     return out
 
 
+def probe_batch(n: int, block: int = 1024) -> int:
+    """Keys one fused probe launch screens for a batch of ``n``: the
+    batch padded to a power-of-two number of ``block``-key blocks, so a
+    serving loop compiles O(log batch) programs, not one per size."""
+    return block << max(-(-n // block) - 1, 0).bit_length()
+
+
 def bloom_probe_multi(filts, meta, keys, block: int = 1024,
                       interpret: bool = False):
     """Probe one key batch against a stack of padded filters (see
     ``stack_filters``) in a single fused launch; returns a (tables, keys)
     bool maybe-present matrix (no false negatives per table).  The batch
-    is padded on the host to a power-of-two number of blocks, so a
-    serving loop compiles O(log batch) programs, not one per size."""
+    is padded on the host to ``probe_batch(len(keys), block)`` keys."""
     t = filts.shape[0]
     n = len(keys)
     if t == 0 or n == 0:
         return np.zeros((t, n), bool)
     meta = np.asarray(meta, np.uint32)
-    blocks = 1 << max(-(-n // block) - 1, 0).bit_length()
-    kp = np.zeros(blocks * block, np.uint32)
+    kp = np.zeros(probe_batch(n, block), np.uint32)
     kp[:n] = np.asarray(keys, np.uint32)
     meta = np.concatenate([meta, reciprocal(meta[:, :1])], axis=1)
     out = bloom_probe_multi_kernel(jnp.asarray(filts), meta,
