@@ -56,8 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.bloom.ops import (bloom_probe_multi,
-                                     bloom_probe_multi_host, probe_batch)
+from repro.kernels.bloom.ops import (ProbeHits, bloom_probe_pruned,
+                                     bloom_probe_pruned_host)
 from repro.kernels.merge.ops import merge_dedup_kway
 
 from .memtable import drop_tombstones
@@ -287,24 +287,20 @@ class ExecBackend:
     # -------------------------------------------------------- entry points
     def probe_multi(self, filts, meta, keys,
                     filts_host: Optional[np.ndarray] = None
-                    ) -> tuple[np.ndarray, int]:
-        """Fused multi-table Bloom probe.  Returns the (tables, keys)
-        maybe-present matrix and the stack rows x keys the launch
-        screened, padding included.  Host mode runs the vectorized numpy
-        probe over ``filts_host`` (the filter stack's host mirror), keys
-        unpadded; kernel modes launch the Pallas probe over the device
-        stack and the batch padded to ``probe_batch`` keys."""
+                    ) -> tuple[ProbeHits, int]:
+        """Fused multi-table Bloom probe, pruned by key range.  ``meta``
+        rows are (n_bits, k, lo, hi): a row probes only the keys inside
+        its table's ``[lo, hi]``.  Returns the maybe-present (row, key)
+        pairs and the (row, key) cells the launch probed.  Host mode
+        probes ``filts_host`` (the filter stack's host mirror) in numpy;
+        kernel modes launch the Pallas probe over the device stack."""
         n_rows = int(filts.shape[0]) if filts is not None \
             else int(filts_host.shape[0])
-        n = len(keys)
-        mode = self.decide("probe_multi", n_rows * n)
+        mode = self.decide("probe_multi", n_rows * len(keys))
         if mode == HOST and filts_host is not None:
-            return (bloom_probe_multi_host(filts_host, np.asarray(meta),
-                                           np.asarray(keys, np.uint32)),
-                    n_rows * n)
-        return (bloom_probe_multi(filts, meta, keys,
-                                  interpret=mode == INTERPRET),
-                n_rows * probe_batch(n) if n_rows and n else 0)
+            return bloom_probe_pruned_host(filts_host, meta, keys)
+        return bloom_probe_pruned(filts, meta, keys,
+                                  interpret=mode == INTERPRET)
 
     def _kernel_merge(self, runs, mode: str, drop_value: Optional[int]):
         return (*merge_dedup_kway(runs, block=self.merge_block,

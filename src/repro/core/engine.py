@@ -103,9 +103,11 @@ Spans and counters: every get/put/scan/pump call opens an ``lsm.*``
 span and one child span per step (``core/tracing.py``), recorded only
 while a profiler trace runs; the wait for the group lock is ``lsm.lock``.
 The counters are the trees' ``stats``; beside the flush, merge and WAL
-counts, each probe launch adds ``probe_cells`` (stack rows x keys it
-screened, padding included) and ``probe_live_cells`` (the (row, key)
-pairs whose table's key range holds the key).
+counts, each probe launch adds ``probe_cells`` (the (row, key) cells
+it probed: the probe prunes each row to the keys inside its table's key
+range) and ``probe_live_cells`` (the (row, key) pairs whose table's key
+range holds the key, counted from the filter stack's ranges); a get's
+``bloom_skips`` are the probed cells its filters ruled out.
 
 Thread safety: every foreground entry point and the background plane
 take the GROUP's reentrant lock internally; ``lock()`` exposes it for
@@ -192,7 +194,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.bloom.ops import set_stack_row
+from repro.kernels.bloom.ops import device_stack, set_stack_row, stack_width
 from .backend import HOST, ExecBackend, merge_kway_host  # noqa: F401
                                         # (merge_kway_host re-export: the
                                         # fleet's scan gather shares it)
@@ -221,12 +223,14 @@ class _ReadView:
     the persistent ``_FilterStack``'s pending journal
     (``LSMTree._view_filters``): ``filts`` is the stack's DEVICE array
     (capacity rows, only live slots meaningful), ``meta`` the host-side
-    per-row (n_bits, k) geometry; each table's probe row is its own
-    ``stack_slot``.  Scan-only workloads never populate them.
+    per-row (n_bits, k, lo, hi); each table's probe row is its own
+    ``stack_slot``, and ``rank`` maps a row to its table's index in
+    ``tables``.  Scan-only workloads never populate them.
     """
     tables: tuple
     filts: Optional["jnp.ndarray"] = None
     meta: Optional[np.ndarray] = None
+    rank: Optional[np.ndarray] = None
 
 
 class _FilterStack:
@@ -250,24 +254,21 @@ class _FilterStack:
     must grow or occupancy falls below 1/4 of capacity — geometric
     sizing, amortized O(rows changed) per background event instead of
     the O(tables * filter-bytes) restack-and-reupload of the per-view
-    ``stack_filters`` path this replaces.  Free rows keep
-    (n_bits=128, k=1) metadata so they never inflate the probe's static
-    ``k_max``; their stale word content is only reachable through a
-    stale (raced, uncached) view's ``stack_slot``.
+    ``stack_filters`` path this replaces.
 
-    ``lo``/``hi`` hold each row's table's first and last key (a free
-    row's ``[1, 0]`` holds none), so ``live_cells`` can count the rows
-    a probe needed without walking the tables.
+    ``meta`` rows are (n_bits, k, lo, hi): each row's filter geometry
+    and its table's first and last key, which the probe prunes by.  Free
+    rows keep (128, 1, 1, 0): their ``[1, 0]`` holds no key, so no probe
+    reads their stale words, and their k never inflates the probe's
+    static ``k_max``.
     """
 
     def __init__(self):
-        self.filts: Optional["jnp.ndarray"] = None   # (cap, width) uint32
+        self.filts: Optional["jnp.ndarray"] = None   # (cap, width // 128, 128)
         self.filts_np: Optional[np.ndarray] = None   # host mirror of the
                                                      # stack — the backend's
                                                      # HOST probe operand
-        self.meta = np.zeros((0, 2), np.uint32)      # host (cap, 2)
-        self.lo = np.ones(0, np.uint32)              # host (cap,) key
-        self.hi = np.zeros(0, np.uint32)             # range of each row
+        self.meta = np.zeros((0, 4), np.uint32)      # host (cap, 4)
         self.slots: dict[int, int] = {}              # component cid -> row
         self.free: list[int] = []
         self._add: dict[int, SSTable] = {}           # pending, cid-keyed
@@ -279,7 +280,7 @@ class _FilterStack:
 
     @property
     def width(self) -> int:
-        return 0 if self.filts is None else int(self.filts.shape[1])
+        return 0 if self.filts_np is None else int(self.filts_np.shape[1])
 
     def note_add(self, table: SSTable) -> None:
         self._add[table.component.cid] = table
@@ -292,25 +293,20 @@ class _FilterStack:
 
     def _rebuild(self, tables) -> None:
         cap = max(4, 2 * len(tables))
-        width = max(max((t.bloom_host().shape[0] for t in tables),
-                        default=1), 1)
+        width = stack_width(max((t.bloom_host().shape[0] for t in tables),
+                                default=1))
         stk = np.zeros((cap, width), np.uint32)
-        self.meta = np.zeros((cap, 2), np.uint32)
-        self.meta[:, 0] = 128
-        self.meta[:, 1] = 1
-        self.lo = np.ones(cap, np.uint32)
-        self.hi = np.zeros(cap, np.uint32)
+        self.meta = np.tile(np.array(_FREE_ROW, np.uint32), (cap, 1))
         self.slots = {}
         for i, t in enumerate(tables):
             w = t.bloom_host()
             stk[i, :w.shape[0]] = w
-            self.meta[i] = (t.n_bits, t.k_hashes)
-            self.lo[i], self.hi[i] = _key_range(t)
+            self.meta[i] = _row_meta(t)
             self.slots[t.component.cid] = i
             t.stack_slot = i
         self.free = list(range(len(tables), cap))
         self.filts_np = stk
-        self.filts = jnp.array(stk)      # independent device copy: row
+        self.filts = device_stack(stk)   # independent device copy: row
                                          # writes donate the device buffer
                                          # and must never alias the mirror
         self._add.clear()
@@ -329,8 +325,7 @@ class _FilterStack:
             row = self.slots.pop(cid, None)
             if row is not None:
                 self.free.append(row)
-                self.meta[row] = (128, 1)
-                self.lo[row], self.hi[row] = 1, 0
+                self.meta[row] = _FREE_ROW
         self._remove.clear()
         if self._add:
             adds = list(self._add.values())
@@ -351,8 +346,7 @@ class _FilterStack:
                 self.filts_np[row] = words        # keep the host mirror
                                                   # (HOST probe operand)
                                                   # in lockstep
-                self.meta[row] = (t.n_bits, t.k_hashes)
-                self.lo[row], self.hi[row] = _key_range(t)
+                self.meta[row] = _row_meta(t)
                 self.slots[t.component.cid] = row
                 t.stack_slot = row
             self._add.clear()
@@ -365,15 +359,20 @@ class _FilterStack:
         whose table's key range holds the key, the only rows that can
         answer it.  One sort and two searches, whatever the rows."""
         sk = np.sort(np.asarray(keys, np.uint32))
-        held = (np.searchsorted(sk, self.hi, "right")
-                - np.searchsorted(sk, self.lo, "left"))
+        held = (np.searchsorted(sk, self.meta[:, 3], "right")
+                - np.searchsorted(sk, self.meta[:, 2], "left"))
         return int(np.maximum(held, 0).sum())
 
 
-def _key_range(t: SSTable) -> tuple[int, int]:
-    """A table's first and last key; an empty table's ``(1, 0)`` holds
-    none."""
-    return (int(t.keys_np[0]), int(t.keys_np[-1])) if len(t) else (1, 0)
+#: a free stack row's (n_bits, k, lo, hi): its key range holds no key
+_FREE_ROW = (128, 1, 1, 0)
+
+
+def _row_meta(t: SSTable) -> tuple[int, int, int, int]:
+    """A table's stack row: filter geometry, first and last key (an
+    empty table's ``[1, 0]`` holds none)."""
+    lo, hi = (int(t.keys_np[0]), int(t.keys_np[-1])) if len(t) else (1, 0)
+    return t.n_bits, t.k_hashes, lo, hi
 
 
 @dataclass
@@ -586,6 +585,9 @@ class LSMTree:
         ``None``s when the bloom kernels are unavailable."""
         if view.filts is None and view.tables and set_stack_row is not None:
             view.filts, view.meta = self._fstack.sync(self._order)
+            view.rank = np.zeros(len(view.meta), np.int64)
+            view.rank[[t.stack_slot for t in view.tables]] = \
+                np.arange(len(view.tables))
         return view.filts, view.meta
 
     def _invalidate_view(self):
@@ -599,10 +601,12 @@ class LSMTree:
 
     def get_batch_locked(self, keys) -> tuple[np.ndarray, np.ndarray]:
         """Vectorized newest-wins lookup over THIS tree (group lock
-        held): memtables newest-first, then ONE fused Bloom probe across
-        all disk tables, then sorted searches only for surviving
-        (table, key) pairs with early exit.  Returns (found, values);
-        tombstone hits resolve the key but report "not found"."""
+        held): memtables newest-first, then ONE fused Bloom probe of
+        the unresolved keys across all disk tables, each table only for
+        the keys inside its key range, then sorted searches only for the
+        maybe-present (table, key) pairs, newest table first.  Returns
+        (found, values); tombstone hits resolve the key but report "not
+        found"."""
         if self.corrupt:
             raise UnrepairableCorruptionError(
                 f"tree {self.name!r} has unrepairable corruption — "
@@ -624,29 +628,32 @@ class LSMTree:
                 view = self._read_view()
                 filts, meta = self._view_filters(view)
             if view.tables:
-                # probe the full stack (capacity rows, <= 2x live
-                # tables); each table's row is its own stack_slot — no
-                # gather.  The backend picks host vs kernel; the host
-                # path probes the stack's host mirror.
+                # ONE probe of the pending keys over the full stack
+                # (capacity rows, <= 2x live tables), each row only for
+                # the keys inside its table's key range; each table's
+                # row is its own stack_slot — no gather.  The backend
+                # picks host vs kernel; the host path probes the stack's
+                # host mirror.
+                pend = np.flatnonzero(~resolved)
                 with span("lsm.get.probe"):
-                    probed, screened = self.group.backend.probe_multi(
-                        filts, meta, keys, filts_host=self._fstack.filts_np)
-                self.stats["probe_cells"] += screened
+                    hits, probed = self.group.backend.probe_multi(
+                        filts, meta, keys[pend],
+                        filts_host=self._fstack.filts_np)
+                self.stats["probe_cells"] += probed
                 self.stats["probe_live_cells"] += \
-                    self._fstack.live_cells(keys)
+                    self._fstack.live_cells(keys[pend])
+                self.stats["bloom_skips"] += probed - len(hits.rows)
                 with span("lsm.get.search"):
-                    for table in view.tables:
-                        pend = ~resolved
-                        if not pend.any():
-                            break
-                        maybe_t = probed[table.stack_slot]
-                        cand = pend & maybe_t
-                        self.stats["bloom_skips"] += int(
-                            (pend & ~maybe_t).sum())
-                        if not cand.any():
+                    # only tables with a maybe-present key, newest first
+                    rank = view.rank[hits.rows]
+                    order = np.argsort(rank, kind="stable")
+                    tabs, first = np.unique(rank[order], return_index=True)
+                    for r, idx in zip(tabs, np.split(pend[hits.keys[order]],
+                                                     first[1:])):
+                        idx = idx[~resolved[idx]]
+                        if not len(idx):
                             continue
-                        idx = np.flatnonzero(cand)
-                        f, v = table.search(keys[idx])
+                        f, v = view.tables[r].search(keys[idx])
                         hit = idx[f]
                         vals[hit] = v[f]
                         resolved[hit] = True

@@ -4,12 +4,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bloom import bloom_probe_multi_kernel, reciprocal
+from .bloom import (LANES, bloom_probe_multi_kernel, reciprocal,
+                    stack_width)
 from .ref import _hash_np
 
 
@@ -66,6 +68,13 @@ def stack_filters(filters, n_bits_list, k_hashes_list):
     return filts, meta
 
 
+def device_stack(stk: np.ndarray):
+    """The device copy of a host (tables, ``stack_width``) uint32 word
+    stack, as the kernel reads it: int32 words shaped (tables, rows,
+    128), so a launch takes the stack in place rather than copying it."""
+    return jnp.array(stk.view(np.int32).reshape(len(stk), -1, LANES))
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _set_row_donated(filts, row, slot):
     return filts.at[slot].set(row)
@@ -77,69 +86,138 @@ def set_stack_row(filts, row_words, slot):
     input-output aliasing update the row IN PLACE — O(row) instead of the
     O(tables * width) restack-and-reupload of ``stack_filters``.  This is
     the engine's incremental read-view maintenance primitive: one call
-    per flush output / merge output.  ``row_words`` shorter than the
-    stack width must be pre-padded by the caller.  The donated input
-    array is consumed — callers must replace every reference with the
-    returned array.  Operands cross the jit boundary raw (the row as
-    host uint32 words, the slot as a Python int): explicit
-    ``jnp.asarray``/``jnp.int32`` staging costs an order of magnitude
-    more dispatch than the row write itself."""
-    return _set_row_donated(filts, row_words, int(slot))
+    per flush output / merge output.  ``row_words`` (host numpy uint32)
+    shorter than the stack width must be pre-padded by the caller.  The
+    donated input array is consumed — callers must replace every
+    reference with the returned array.  Operands cross the jit boundary
+    raw (the row as host words in the stack's shape, the slot as a
+    Python int): explicit ``jnp.asarray``/``jnp.int32`` staging costs an
+    order of magnitude more dispatch than the row write itself."""
+    return _set_row_donated(filts, np.asarray(row_words, np.uint32)
+                            .view(np.int32).reshape(filts.shape[1:]),
+                            int(slot))
+
+
+class ProbeHits(NamedTuple):
+    """Maybe-present (row, key) pairs of a pruned probe, rows ascending:
+    ``rows`` are stack rows, ``keys`` indices into the probed batch."""
+    rows: np.ndarray
+    keys: np.ndarray
+
+
+def _live_pairs(meta, keys):
+    """Every (row, key) pair whose row's ``[lo, hi]`` (``meta`` columns 2
+    and 3, uint32 compares; ``lo > hi`` holds none) holds the key.  The
+    live keys of a row are one window of the sorted batch, found by one
+    search per bound.  Returns the batch's sort order, each row's window
+    ``(start, count)``, and the pairs as (rows ascending, sorted key
+    positions), in O(pairs + rows) past the sort."""
+    order = np.argsort(keys, kind="stable")
+    sk = keys[order]
+    start = np.searchsorted(sk, meta[:, 2], "left")
+    count = np.maximum(np.searchsorted(sk, meta[:, 3], "right") - start, 0)
+    rows = np.repeat(np.arange(len(count)), count)
+    base = np.repeat(start - np.cumsum(count) + count, count)
+    return order, start, count, rows, base + np.arange(len(rows))
+
+
+def _probe_pairs_host(filts_np, meta, keys, rows, qs) -> np.ndarray:
+    """Maybe-present flag of key ``keys[qs[p]]`` in stack row
+    ``rows[p]``, for each pair ``p``: the kernel's double hashing over
+    the host mirror, lanes beyond a row's own k passing."""
+    key = np.asarray(keys, np.uint32)[qs]
+    h1 = _hash_np(key, 0x9E3779B9)
+    h2 = _hash_np(key, 0x85EBCA6B) | np.uint32(1)
+    n_bits = meta[rows, 0].astype(np.uint32)
+    k = meta[rows, 1].astype(np.int64)
+    out = np.ones(len(rows), bool)
+    for i in range(int(k.max(initial=0))):
+        pos = ((h1 + np.uint32(i) * h2) % n_bits).astype(np.int64)
+        bit = (filts_np[rows, pos >> 5] >> (pos & 31).astype(np.uint32)) \
+            & np.uint32(1)
+        out &= (bit == 1) | (i >= k)
+    return out
 
 
 def bloom_probe_multi_host(filts_np: np.ndarray, meta: np.ndarray,
                            keys: np.ndarray) -> np.ndarray:
     """Host twin of ``bloom_probe_multi``: the same double-hashing probe
-    over the HOST mirror of the stacked filter words, pure numpy — the
-    execution backend's CPU fast path for the fused probe (bit-identical
-    to the kernel by construction: same hash family, same per-row
-    geometry semantics, unused hash lanes pass).
+    over the HOST mirror of the stacked filter words, pure numpy (bit-
+    identical to the kernel by construction: same hash family, same
+    per-row geometry semantics, unused hash lanes pass).
 
     ``filts_np`` is (tables, words) uint32, ``meta`` (tables, 2) uint32
-    rows of (n_bits, k_hashes).  Returns a (tables, keys) bool matrix.
-    Rows iterate in Python (tables are tens, keys are the batch — the
-    inner work is vectorized numpy over (k, q))."""
-    keys = np.asarray(keys, np.uint32)
+    rows of (n_bits, k_hashes).  Returns a (tables, keys) bool matrix."""
     t, q = int(filts_np.shape[0]), len(keys)
-    out = np.zeros((t, q), bool)
-    if t == 0 or q == 0:
-        return out
-    h1 = _hash_np(keys, 0x9E3779B9)
-    h2 = _hash_np(keys, 0x85EBCA6B) | np.uint32(1)
-    i_max = np.arange(int(meta[:, 1].max()), dtype=np.uint32)[:, None]
-    for r in range(t):
-        n_bits = np.uint32(meta[r, 0])
-        k = int(meta[r, 1])
-        pos = ((h1[None, :] + i_max[:k] * h2[None, :]) % n_bits) \
-            .astype(np.int64)                           # (k, q)
-        words = filts_np[r, pos >> 5]
-        bits = (words >> (pos & 31).astype(np.uint32)) & np.uint32(1)
-        out[r] = bits.min(axis=0).astype(bool)
-    return out
+    rows, qs = np.divmod(np.arange(t * q), max(q, 1))
+    meta = np.asarray(meta, np.uint32)
+    return _probe_pairs_host(filts_np, meta, keys, rows, qs).reshape(t, q)
 
 
 def probe_batch(n: int, block: int = 1024) -> int:
-    """Keys one fused probe launch screens for a batch of ``n``: the
+    """Keys one fused probe launch takes for a batch of ``n``: the
     batch padded to a power-of-two number of ``block``-key blocks, so a
     serving loop compiles O(log batch) programs, not one per size."""
     return block << max(-(-n // block) - 1, 0).bit_length()
 
 
+def _launch(filts, meta, keys, start, count, block, interpret):
+    """One kernel launch over ``keys`` padded to ``probe_batch``;
+    returns the (tables, padded keys / 32) maybe-present bits."""
+    meta = np.asarray(meta, np.uint32)
+    kp = np.zeros(probe_batch(len(keys), block), np.uint32)
+    kp[:len(keys)] = keys
+    table = np.column_stack([meta[:, :2], reciprocal(meta[:, 0]), start,
+                             count]).astype(np.uint32)
+    return np.asarray(bloom_probe_multi_kernel(
+        jnp.asarray(filts), table, kp, k_max=int(meta[:, 1].max()),
+        block=block, interpret=interpret))
+
+
 def bloom_probe_multi(filts, meta, keys, block: int = 1024,
                       interpret: bool = False):
     """Probe one key batch against a stack of padded filters (see
-    ``stack_filters``) in a single fused launch; returns a (tables, keys)
-    bool maybe-present matrix (no false negatives per table).  The batch
-    is padded on the host to ``probe_batch(len(keys), block)`` keys."""
+    ``stack_filters``) in a single fused launch, every row against every
+    key; returns a (tables, keys) bool maybe-present matrix (no false
+    negatives per table)."""
     t = filts.shape[0]
     n = len(keys)
     if t == 0 or n == 0:
         return np.zeros((t, n), bool)
+    bits = _launch(filts, meta, np.asarray(keys, np.uint32),
+                   np.zeros(t, np.int32), np.full(t, n, np.int32),
+                   block, interpret)
+    return np.unpackbits(np.ascontiguousarray(bits).view(np.uint8), axis=1,
+                         count=n, bitorder="little").astype(bool)
+
+
+def bloom_probe_pruned(filts, meta, keys, block: int = 1024,
+                       interpret: bool = False) -> tuple[ProbeHits, int]:
+    """The fused probe pruned by key range: ``meta`` rows are (n_bits,
+    k_hashes, lo, hi), and a row probes only the keys inside its table's
+    ``[lo, hi]`` — a table whose key range misses a key cannot hold it,
+    so the answer is exact.  Launches the kernel over each row's window
+    of the sorted batch and reads back one bit per (row, padded key).
+    Returns the maybe-present pairs and the (row, key) cells probed."""
+    keys = np.asarray(keys, np.uint32)
     meta = np.asarray(meta, np.uint32)
-    kp = np.zeros(probe_batch(n, block), np.uint32)
-    kp[:n] = np.asarray(keys, np.uint32)
-    meta = np.concatenate([meta, reciprocal(meta[:, :1])], axis=1)
-    out = bloom_probe_multi_kernel(jnp.asarray(filts), meta,
-                                   kp, k_max=int(meta[:, 1].max()),
-                                   block=block, interpret=interpret)
-    return np.asarray(out)[:, :n].astype(bool)
+    order, start, count, rows, qs = _live_pairs(meta, keys)
+    cells = len(rows)
+    if cells:
+        bits = _launch(filts, meta, keys[order], start, count, block,
+                       interpret)
+        hit = (bits[rows, qs >> 5] >> (qs & 31).astype(np.uint32)) & 1 == 1
+        rows, qs = rows[hit], qs[hit]
+    return ProbeHits(rows, order[qs]), cells
+
+
+def bloom_probe_pruned_host(filts_np: np.ndarray, meta: np.ndarray,
+                            keys: np.ndarray) -> tuple[ProbeHits, int]:
+    """Host twin of ``bloom_probe_pruned``: the same pairs, probed over
+    the host mirror."""
+    keys = np.asarray(keys, np.uint32)
+    meta = np.asarray(meta, np.uint32)
+    order, _, _, rows, qs = _live_pairs(meta, keys)
+    qs = order[qs]
+    hit = _probe_pairs_host(filts_np, meta, keys, rows, qs)
+    return ProbeHits(rows[hit], qs[hit]), len(rows)

@@ -326,6 +326,43 @@ def test_engine_legacy_flags_are_backend_views():
     assert eng2.backend.decide("merge_kway", 1) == HOST
 
 
+def _probe_stack(rng, tables: int = 6, space: int = 1 << 16):
+    """A filter stack as the engine keeps it: tables over overlapping
+    key ranges, a free row between them, rows padded to whole tiles."""
+    from repro.kernels.bloom.ops import (bloom_build, filter_params,
+                                         stack_filters, stack_width)
+    keys = [np.unique(rng.integers(lo, lo + space // 4, 300,
+                                   dtype=np.uint32))
+            for lo in rng.integers(0, 3 * space // 4, tables)]
+    geo = [filter_params(len(k)) for k in keys]
+    filts, meta = stack_filters([bloom_build(k, *g)
+                                 for k, g in zip(keys, geo)], *zip(*geo))
+    filts = np.pad(filts, ((0, 1), (0, stack_width(filts.shape[1])
+                                    - filts.shape[1])))
+    ranges = [[k[0], k[-1]] for k in keys] + [[1, 0]]
+    meta = np.column_stack([np.vstack([meta, [128, 1]]), ranges])
+    return filts, meta.astype(np.uint32)
+
+
+@pytest.mark.parametrize("mode", ALL_MODES)
+def test_probe_multi_prunes_by_key_range_in_every_mode(mode):
+    """``probe_multi`` returns the same maybe-present pairs in every
+    mode: the dense probe's pairs whose row's range holds the key, the
+    free row's none, and as probed cells the live ones."""
+    from repro.kernels.bloom.ops import bloom_probe_multi_host, device_stack
+    rng = np.random.default_rng(4)
+    filts, meta = _probe_stack(rng)
+    q = rng.integers(0, 1 << 16, 700, dtype=np.uint32)
+    live = (q >= meta[:, 2:3]) & (q <= meta[:, 3:4])
+    want = bloom_probe_multi_host(filts, meta[:, :2], q) & live
+    hits, probed = ExecBackend(mode=mode).probe_multi(
+        device_stack(filts), meta, q, filts_host=filts)
+    got = np.zeros(want.shape, bool)
+    got[hits.rows, hits.keys] = True
+    np.testing.assert_array_equal(got, want)
+    assert probed == live.sum() and not live[-1].any()
+
+
 # ------------------------------------------------------------ fleet pin
 class _SpyBackend(ExecBackend):
     def __init__(self, **kw):

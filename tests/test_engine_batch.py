@@ -8,6 +8,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
+from repro.core.backend import ExecBackend
 from repro.core.constraints import GlobalConstraint, NoConstraint
 from repro.core.engine import LSMEngine
 from repro.core.memtable import MemTable
@@ -107,6 +108,60 @@ def test_scan_and_get_agree_on_ordering():
     found, vals = eng.get_batch(keys)
     assert found.all()
     assert {int(k): int(v) for k, v in zip(keys, vals)} == ref
+
+
+class _DenseProbe(ExecBackend):
+    """Probes every table's row for every key, as before the probe was
+    pruned by key range: each table's range widened to all keys (free
+    rows keep theirs, which holds none)."""
+
+    def probe_multi(self, filts, meta, keys, filts_host=None):
+        meta = np.array(meta)
+        meta[meta[:, 2] <= meta[:, 3], 2:] = (0, 2 ** 32 - 1)
+        return super().probe_multi(filts, meta, keys, filts_host=filts_host)
+
+
+@pytest.mark.parametrize("mode", ["host", "interpret"])
+def test_get_batch_pruned_equals_dense_probing(mode):
+    """A partitioned-leveling store answers every batch identically with
+    the probe pruned by key range and with every row probed for every
+    key, as flushes and merges rewrite stack rows and their ranges;
+    both agree with a dict oracle, deletes included."""
+    rng = np.random.default_rng(23)
+    eng = LSMEngine(PartitionedLevelingPolicy(4, 64, 4096, file_entries=64,
+                                              l1_capacity=256),
+                    GreedyScheduler(), GlobalConstraint(200),
+                    memtable_entries=64, unique_keys=4096, merge_block=64,
+                    backend=ExecBackend(mode=mode))
+    pruned, dense = eng.backend, _DenseProbe(mode="host")
+    ref, ranges, seen = {}, set(), {"flushes": 0, "merges": 0}
+    for step in range(12):
+        keys = rng.integers(0, 4096, 150, dtype=np.uint32)
+        vals = rng.integers(0, 1 << 30, 150).astype(np.int32)
+        taken = eng.put_batch(keys, vals)
+        ref.update(zip(keys[:taken].tolist(), vals[:taken].tolist()))
+        gone = keys[:taken][::7]
+        for k in gone[:eng.delete_batch(gone)].tolist():
+            ref.pop(k, None)
+        eng.pump(200)
+        q = np.concatenate([keys, rng.integers(0, 4096, 200,
+                                               dtype=np.uint32)])
+        got = eng.get_batch(q)
+        eng.backend = dense
+        want = eng.get_batch(q)
+        eng.backend = pruned
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)
+        assert [int(v) if f else None for f, v in zip(*got)] == \
+            [ref.get(int(k)) for k in q]
+        fs = eng._fstack
+        ranges.add(fs.meta[list(fs.slots.values()), 2:].tobytes())
+        for t in eng.tables.values():
+            assert tuple(fs.meta[t.stack_slot, 2:]) == \
+                (t.keys_np[0], t.keys_np[-1])
+        seen = {k: eng.stats[k] for k in seen}
+    assert seen["flushes"] > 2 and seen["merges"] > 2
+    assert len(ranges) > 8          # rows and ranges kept changing
 
 
 # --------------------------------------------------------------- writes

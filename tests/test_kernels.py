@@ -180,6 +180,109 @@ def test_bloom_probe_multi_equals_per_table():
                              interpret=True).shape == (len(tables), 0)
 
 
+#: a free stack row: its range holds no key, and its words are all ones,
+#: so a probe that read it would answer "maybe" for every key
+FREE = (None, 1, 0)
+TOP = 2 ** 32 - 2                       # the largest key a store takes
+
+
+def _table(rng, lo, hi, n):
+    keys = np.unique(rng.integers(lo, hi + 1, n, dtype=np.uint64)
+                     .astype(np.uint32))
+    return keys, int(keys[0]), int(keys[-1])
+
+
+def _pruning_case(case, rng):
+    """Stack rows, each ``(keys, lo, hi)`` (``keys`` None for a free
+    row), and a key batch for one pruning case."""
+    a = _table(rng, 1000, 2000, 200)
+    b = _table(rng, 3000, 9000, 300)
+    if case == "empty_row":
+        return [a, FREE, b], rng.integers(0, 10000, 300, dtype=np.uint32)
+    if case == "keys_at_lo_and_hi":
+        c = _table(rng, 2000, 3000, 100)   # may share a bound with a or b
+        q = np.array([a[1], a[2], b[1], b[2], c[1], c[2], a[1] - 1,
+                      a[2] + 1, b[2] + 1, *a[0][:40]], np.uint32)
+        return [a, b, c], q
+    if case == "keys_above_2_31":
+        hi1 = _table(rng, 2 ** 31, 2 ** 31 + 5000, 300)
+        hi2 = _table(rng, TOP - 4000, TOP, 200)
+        q = np.concatenate([hi1[0][::3], hi2[0][::2],
+                            rng.integers(2 ** 31 - 100, 2 ** 31 + 100, 50,
+                                         dtype=np.uint32),
+                            np.array([TOP, 2 ** 31 - 1, 0], np.uint32)])
+        return [a, hi1, hi2], q
+    if case == "row_holding_every_key":
+        run = (_table(rng, 0, 20000, 500)[0], 0, TOP)
+        return [run, a, run], rng.integers(0, 30000, 400, dtype=np.uint32)
+    if case == "no_live_row":
+        return [a, FREE, b], rng.integers(10000, 20000, 200,
+                                          dtype=np.uint32)
+    if case == "batch_off_128_over_two_blocks":
+        # rows whose windows lie in the first, both and the second
+        # 1,024-key block of the sorted batch
+        wide = _table(rng, 0, 6000, 400)
+        late = _table(rng, 10000, 11500, 300)
+        return [FREE, late, a, b, wide, FREE, late], \
+            rng.integers(0, 12000, 1500, dtype=np.uint32)
+    if case == "duplicate_keys":
+        q = np.repeat(np.concatenate([a[0][:50], b[0][:50],
+                                      rng.integers(0, 10000, 50,
+                                                   dtype=np.uint32)]), 3)
+        return [a, b], rng.permutation(q)
+    assert case == "free_rows_between_live_ones"
+    c = _table(rng, 500, 4000, 250)
+    return [FREE, a, FREE, b, FREE, FREE, c, FREE], \
+        rng.integers(0, 10000, 300, dtype=np.uint32)
+
+
+@pytest.mark.parametrize("case", [
+    "empty_row", "keys_at_lo_and_hi", "keys_above_2_31",
+    "row_holding_every_key", "no_live_row", "batch_off_128_over_two_blocks",
+    "duplicate_keys", "free_rows_between_live_ones"])
+def test_pruned_probe_is_dense_probe_and_key_range(case):
+    """The probe pruned by key range answers, for every (row, key), the
+    dense probe ANDed with ``lo <= key <= hi``: the interpret-mode kernel
+    on the device stack, the host twin, and the ``ref.py`` bit-set
+    agree, free rows answer nothing, and each path reports the live
+    cells as the cells it probed."""
+    from repro.kernels.bloom.ops import (bloom_probe_multi,
+                                         bloom_probe_pruned,
+                                         bloom_probe_pruned_host,
+                                         device_stack, stack_filters,
+                                         stack_width)
+    rng = np.random.default_rng(sum(map(ord, case)))
+    rows, q = _pruning_case(case, rng)
+    geo = [filter_params(len(k)) if k is not None else (128, 1)
+           for k, _, _ in rows]
+    filts, meta = stack_filters(
+        [bloom_build(k, *g) if k is not None
+         else np.full(4, 2 ** 32 - 1, np.uint32) for (k, _, _), g
+         in zip(rows, geo)], *zip(*geo))
+    filts = np.pad(filts, ((0, 0), (0, stack_width(filts.shape[1])
+                                    - filts.shape[1])))
+    meta = np.column_stack([meta, [[lo, hi] for _, lo, hi in rows]]) \
+        .astype(np.uint32)
+    live = np.array([(q >= lo) & (q <= hi) for _, lo, hi in rows])
+    want = np.zeros(live.shape, bool)
+    for r, ((keys, _, _), (n_bits, k)) in enumerate(zip(rows, geo)):
+        if keys is not None:
+            want[r] = bloom_probe_ref(bloom_build_ref(keys, n_bits, k), q,
+                                      n_bits, k) & live[r]
+    dense = bloom_probe_multi(filts, meta[:, :2], q, interpret=True)
+    np.testing.assert_array_equal(dense & live, want)
+    for hits, cells in (
+            bloom_probe_pruned(device_stack(filts), meta, q,
+                               interpret=True),
+            bloom_probe_pruned_host(filts, meta, q)):
+        got = np.zeros(live.shape, bool)
+        got[hits.rows, hits.keys] = True
+        np.testing.assert_array_equal(got, want)
+        assert cells == live.sum()
+        assert len(hits.rows) == want.sum()        # each pair once
+        assert (np.diff(hits.rows) >= 0).all()
+
+
 # ------------------------------------------------------------- attention
 @pytest.mark.parametrize("B,H,Hkv,S,D,bq,bk", [
     (1, 2, 1, 64, 16, 32, 32),

@@ -18,7 +18,8 @@ import numpy as np
 import pytest
 
 from repro.core.backend import merge_kway_host
-from repro.kernels.bloom.bloom import bloom_probe_multi_kernel
+from repro.kernels.bloom.bloom import (_VMEM_CAP, bloom_probe_multi_kernel,
+                                      stack_width)
 from repro.kernels.bloom.ops import filter_params
 from repro.kernels.merge import ops as merge_ops
 from repro.kernels.merge.merge import AGE_PAD, KEY_PAD, LANES
@@ -154,7 +155,32 @@ def test_bloom_probe_compiles_for_table_stack(one_chip):
     words = n_bits // 32
     compiled = bloom_probe_multi_kernel.lower(
         _spec(one_chip, (PROBE_TABLES, words), jnp.uint32),
-        _spec(one_chip, (PROBE_TABLES, 3), jnp.uint32),
+        _spec(one_chip, (PROBE_TABLES, 5), jnp.uint32),
         _spec(one_chip, (PROBE_BATCH,), jnp.uint32),
         k_max=7, block=1024, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: the benchmark's filter stacks: partitioned leveling's 1,526 files of
+#: 65,536 records in 3,052 rows, tiering's 7 runs, the widest of 243
+#: memtables of 131,072, in 14 rows; reads of up to 1,024 and 4,096 keys
+@pytest.mark.parametrize("rows,table_keys,batch", [
+    (3052, 1 << 16, 1024),
+    (14, 243 << 17, 4096),
+])
+def test_pruned_probe_compiles_for_benchmark_stacks(one_chip, rows,
+                                                    table_keys, batch):
+    """The probe as the engine launches it: the device stack read in
+    place, per-row windows, one double-buffered filter row within the
+    kernel's VMEM cap, and no copy of the stack beside it."""
+    n_bits, k = filter_params(table_keys)
+    width = stack_width(n_bits // 32)
+    assert 2 * width * 4 + (8 << 20) <= _VMEM_CAP
+    compiled = bloom_probe_multi_kernel.lower(
+        _spec(one_chip, (rows, width // LANES, LANES)),
+        _spec(one_chip, (rows, 5), jnp.uint32),
+        _spec(one_chip, (batch,), jnp.uint32),
+        k_max=k, block=1024, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    stack_bytes = rows * width * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < stack_bytes // 8

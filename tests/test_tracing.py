@@ -133,8 +133,7 @@ def _live_cells(tables, keys) -> int:
 def test_probe_counters(backend):
     """Two flushed tables over disjoint key ranges (the third batch
     stays in the memtable): a key is live in at most one, and the
-    launch screened every stack row, the keys padded on the kernel
-    path."""
+    launch probed only those live cells, on either path."""
     eng = _engine(backend=backend)
     for t in range(3):
         keys = np.arange(t * 1000, t * 1000 + MEMTABLE, dtype=np.uint32)
@@ -145,11 +144,9 @@ def test_probe_counters(backend):
     eng.get_batch(q)
     d = {k: eng.stats[k] - before[k] for k in COUNTERS}
     tables = list(eng.tables.values())
-    rows = eng._fstack.cap
-    assert len(tables) == 2 and rows >= len(tables)
+    assert len(tables) == 2 and eng._fstack.cap >= len(tables)
     assert _live_cells(tables, q) == 4
-    padded = len(q) if backend == "host" else probe_batch(len(q))
-    assert d == {"probe_cells": rows * padded, "probe_live_cells": 4}
+    assert d == {"probe_cells": 4, "probe_live_cells": 4}
 
 
 def test_live_cells_follow_the_filter_stack():
@@ -168,6 +165,30 @@ def test_live_cells_follow_the_filter_stack():
         eng.get_batch(q)
         assert eng.stats["probe_live_cells"] - before == \
             _live_cells(eng.tables.values(), q)
+
+
+@pytest.mark.parametrize("backend", ["host", "interpret"])
+def test_probe_cells_are_the_cells_probed(backend):
+    """Over tiering runs whose key ranges overlap, with keys resolved in
+    the memtable left out of the probe: ``probe_cells`` is the (row,
+    key) cells the launch probed, each row's keys inside its table's
+    range, never fewer than ``probe_live_cells``, and the share the
+    benchmark reads from the two is 100% when every probed cell is
+    live."""
+    from lsmbench.metrics import probe_live_share
+    eng = _engine(backend=backend)
+    rng = np.random.default_rng(9)
+    _fill(eng, rng, 5 * MEMTABLE)
+    eng.put_batch(np.arange(8, dtype=np.uint32), np.ones(8, np.int32))
+    q = np.concatenate([np.arange(8, dtype=np.uint32),
+                        rng.integers(0, KEYS, 300, dtype=np.uint32)])
+    before = eng.stats
+    eng.get_batch(q)
+    d = {k: eng.stats[k] - before[k] for k in COUNTERS}
+    assert d["probe_cells"] == _live_cells(eng.tables.values(), q[8:])
+    assert 0 < d["probe_live_cells"] <= d["probe_cells"]
+    run = type("Run", (), {"stats_delta": d})
+    assert probe_live_share.read(run) == 100.0
 
 
 def test_a_get_answered_by_memtables_launches_no_probe():
